@@ -55,6 +55,9 @@ def main(argv=None) -> None:
                    help="comma-separated subset of benchmark modules")
     args = p.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (aggregation, latency, lm_roofline, loss_budget,
                             pipeline, resilience, telemetry, topology)
 

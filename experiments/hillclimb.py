@@ -5,13 +5,11 @@ print the roofline-term deltas vs baseline.
       '{"ssm_impl": "ssd"}' '{"ssm_impl": "ssd", "ssd_chunk": 256}'
 """
 
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import json
 import sys
 
 from repro.launch.dryrun import lower_cell
+from repro.launch.mesh import force_host_devices
 from benchmarks import roofline
 
 
@@ -46,8 +44,13 @@ def run(arch, shape, variants, out_path=None):
     return rows
 
 
-if __name__ == "__main__":
+def main() -> None:
+    force_host_devices(512)
     arch, shape = sys.argv[1], sys.argv[2]
     variants = [json.loads(a) for a in sys.argv[3:]]
     run(arch, shape, variants,
         out_path=f"experiments/perf_{arch}_{shape}.jsonl")
+
+
+if __name__ == "__main__":
+    main()

@@ -171,6 +171,17 @@ def _resolve(
     raise TypeError(f"cannot resolve transport from {spec!r}")
 
 
+def _chip_major(events: ev.EventBuffer, chip_axis: int) -> ev.EventBuffer:
+    """Move the chip axis of a batched event block to the front.
+
+    The local fabric vmaps its per-chip body over chips, and a Pallas
+    kernel under vmap gains its batch axis wherever its operand carried
+    it; on a TPU a block may not split the last two dimensions of an
+    array, so the chip axis must lead.
+    """
+    return jax.tree.map(lambda x: jnp.moveaxis(x, chip_axis, 0), events)
+
+
 class FabricResult(NamedTuple):
     """What one fabric step returns.
 
@@ -965,9 +976,8 @@ class PulseFabric:
         if self.batched:
             ring, delivered, stats, flow, merge, sendq = jax.vmap(
                 self._chip_superstep, axis_name=LOCAL_AXIS,
-                in_axes=(1, 0, 0, 0, 0, 0),
                 out_axes=(0, 1, 1, 0, 0, 0),
-            )(events, table, ring, flow, merge, sendq)
+            )(_chip_major(events, 1), table, ring, flow, merge, sendq)
         else:
             ring, delivered, stats, flow, merge, sendq = (
                 self._chip_superstep(events, table, ring, flow, merge,
@@ -1180,9 +1190,9 @@ class PulseFabric:
         if self.batched:
             out = jax.vmap(
                 self._chip_pipeline_block, axis_name=LOCAL_AXIS,
-                in_axes=(1, 0, 0, 0, 0, 0, 0),
                 out_axes=(0, 1, 1, 0, 0, 0, 0),
-            )(events, table, ring, flow, merge, sendq, pending)
+            )(_chip_major(events, 1), table, ring, flow, merge, sendq,
+              pending)
         else:
             out = self._chip_pipeline_block(
                 events, table, ring, flow, merge, sendq, pending)
@@ -1256,9 +1266,8 @@ class PulseFabric:
         if self.batched:
             out = jax.vmap(
                 self._chip_run_pipelined, axis_name=LOCAL_AXIS,
-                in_axes=(2, 0, 0, 0, 0, 0),
                 out_axes=(0, 2, 2, 0, 0, 0, 0),
-            )(events, table, ring, flow, merge, sendq)
+            )(_chip_major(events, 2), table, ring, flow, merge, sendq)
         else:
             out = self._chip_run_pipelined(
                 events, table, ring, flow, merge, sendq)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 
 from repro.core import events as ev
 
-_INF = jnp.int32(2**30)
+_INF = 2**30
 
 
 def merge_words(words: jax.Array, now: jax.Array) -> jax.Array:

@@ -29,12 +29,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(name: str) -> int:
-    # jax.lax.axis_size does not exist on this jax version; psum of the
-    # literal 1 over a named axis constant-folds to the static axis size.
-    return jax.lax.psum(1, name)
-
-
 class Transport(Protocol):
     n_chips: int
 
@@ -79,7 +73,7 @@ class ShardMapTransport:
         # Split this stage's dim [P * Q, ...] -> [P, Q, ...] for axes
         # (outer, *inner): inner stages exchange each outer-block in place,
         # then the outer stage crosses with one aggregated slab per block.
-        p = _axis_size(axes[0])
+        p = jax.lax.axis_size(axes[0])
         q = x.shape[axis] // p
         y = x.reshape(x.shape[:axis] + (p, q) + x.shape[axis + 1:])
         y = self._a2a(y, axes[1:], axis + 1)
@@ -100,7 +94,7 @@ class ShardMapTransport:
         axes = self._axes()
         idx = jax.lax.axis_index(axes[0])
         for a in axes[1:]:
-            idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
 
 

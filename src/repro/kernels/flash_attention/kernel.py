@@ -25,10 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # jax >= 0.7 name
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -143,28 +140,19 @@ def flash_attention_pallas(
         _kernel, scale=scale, causal=causal, kv_len=kv_len,
         block_q=block_q, block_k=block_k, n_kb=n_kb, q_offset=q_offset,
     )
-    scratch = None
-    compiler_params = None
-    if pltpu is not None:
-        scratch = [
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ]
-        cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cp_cls is not None:
-            compiler_params = cp_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
+    scratch = [
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q, d), jnp.float32),
+    ]
 
     q3 = q.reshape(b * hq, sq, d)
     k3 = k.reshape(b * hkv, skv, d)
     v3 = v.reshape(b * hkv, skv, d)
     kwargs = {}
-    if compiler_params is not None and not interpret:
-        kwargs["compiler_params"] = compiler_params
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -6,7 +6,7 @@ substeps and runs, per substep k against clock ``t0 + k``:
 
   1. the merge stage — ``sort`` mode time-orders substep k's words with
      the same bitonic network the standalone ``merge_sort`` kernel uses
-     (reused compare-exchange substages, (key, idx, word) lanes ==
+     (``merge_sort.kernel.bitonic_sort``, (key, idx, word) lanes ==
      stable); ``rate`` mode additionally threads the bounded queue
      through the network (concat queue + arrivals + rate sentinels, sort,
      emit the first ``rate`` lanes, keep the [rate, rate+depth) window —
@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import events as ev
-from repro.kernels.merge_sort.kernel import _compare_exchange
+from repro.kernels.common import onehot_dot
+from repro.kernels.merge_sort.kernel import bitonic_sort
 
 _SENTINEL = ev.WORD_SENTINEL
 _TIME_MASK = ev.WORD_TIME_MASK
@@ -55,21 +56,11 @@ def _iota(shape, dim):
 def _sort_row(row, now_k):
     """Stable ascending bitonic sort of a [1, n] word row by the wrap-aware
     deadline key relative to ``now_k`` (events.word_sort_key semantics);
-    n must be a power of two."""
-    n = row.shape[1]
-    word = row[0, :]
-    key = jnp.where(word >= 0, (word - now_k + _HALF) & _TIME_MASK,
+    n must be a power of two >= 128."""
+    key = jnp.where(row >= 0, (row - now_k + _HALF) & _TIME_MASK,
                     jnp.int32(ev.TIME_MOD))
-    idx = _iota((1, n), 1)[0, :]
-    lanes = (key, idx, word)
-    k = 2
-    while k <= n:          # static network: unrolled at trace time
-        j = k // 2
-        while j >= 1:
-            lanes = _compare_exchange(lanes, k, j, n)
-            j //= 2
-        k *= 2
-    return lanes[2].reshape(1, n)
+    (out,) = bitonic_sort(key, row)
+    return out
 
 
 def _deposit(ring, row, now_k, min_ahead, depth, n_inputs):
@@ -89,8 +80,7 @@ def _deposit(ring, row, now_k, min_ahead, depth, n_inputs):
     sl = ((_iota((depth, row.shape[1]), 0) == slot)
           & deliverable).astype(jnp.int32)
     co = (_iota((n_inputs, row.shape[1]), 0) == col).astype(jnp.int32)
-    acc = jax.lax.dot_general(sl, co, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.int32)
+    acc = onehot_dot(sl, co, (((1,), (1,)), ((), ())))
     return ring + acc, expired
 
 

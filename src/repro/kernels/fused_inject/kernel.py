@@ -6,12 +6,15 @@ substeps resident in VMEM and runs, per substep k against clock ``t0 + k``:
   1. routing-LUT lookup — TPU has no fast random VMEM gather, so the LUT
      read is a one-hot compare ([N, E] ``broadcasted_iota`` match against
      the clamped addresses) contracted with the ``[N, 4]`` table matrix in
-     a single int32 MXU matmul (``preferred_element_type=jnp.int32``);
+     a single MXU matmul (f32 at ``HIGHEST`` precision via
+     ``kernels.common.onehot_dot`` — exact, and the MXU takes no int32);
   2. reachability cull (health mask) and the 8-bit wrap-window admission
      with the remaining deferral ``B-1-k`` as extra slack — exactly the
      judgment of :meth:`repro.core.fabric.PulseFabric._inject_block`;
   3. wire-word encode + flush-slab scatter: rank-within-bucket via the
-     one-hot cumsum of ``repro.core.buckets.compute_slots``, then a
+     one-hot prefix sum of ``repro.core.buckets.compute_slots`` (a
+     log-step lane-rotation scan, ``kernels.common.exclusive_cumsum``:
+     Mosaic has no ``cumsum``), then a
      slot-selection reduce onto the combined ``bucket * capacity + slot``
      code (scatter-free: ``slab[r] = Σ_e [code_e == r] · word_e``, with a
      hit count deciding sentinel fill because word value 0 is a *valid*
@@ -48,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import events as ev
+from repro.kernels.common import exclusive_cumsum, onehot_dot
 
 _SENTINEL = ev.WORD_SENTINEL
 _ADDR_SENTINEL = ev.ADDR_SENTINEL
@@ -88,9 +92,7 @@ def _inject_substep(
     addr_m = jnp.where(addr_m < 0, addr_m + n_real, addr_m)
     addr_c = jnp.clip(addr_m, 0, n_real - 1)
     match = (_iota((table.shape[0], e), 0) == addr_c).astype(jnp.int32)
-    fields = jax.lax.dot_general(
-        table, match, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)            # [4, E]
+    fields = onehot_dot(table, match, (((0,), (0,)), ((), ())))  # [4, E]
     dc, da = fields[0:1, :], fields[1:2, :]
     dly, tv = fields[2:3, :], fields[3:4, :]
 
@@ -105,9 +107,7 @@ def _inject_substep(
     # Reachability cull (all-ones reach row == no health mask: identity).
     dc_clip = jnp.clip(dest_chip, 0, n_chips - 1)
     hot = (_iota((n_chips, e), 0) == dc_clip).astype(jnp.int32)
-    reach_g = jax.lax.dot_general(
-        reach_row, hot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)            # [1, E]
+    reach_g = onehot_dot(reach_row, hot, (((1,), (0,)), ((), ())))  # [1, E]
     in_range = (dest_chip >= 0) & (dest_chip < n_chips)
     ok = ~in_range | (reach_g != 0)
     lost = count(valid & ~ok)
@@ -125,12 +125,11 @@ def _inject_substep(
         win = (deadline // max(time_window, 1)) % buckets_per_chip
         bid = dest_chip * buckets_per_chip + win
 
-    # Rank within bucket: one-hot cumsum (compute_slots, transposed).
+    # Rank within bucket: one-hot prefix sum (compute_slots, transposed).
     oh = ((_iota((nb, e), 0) == bid) & valid).astype(jnp.int32)
-    incl = jnp.cumsum(oh, axis=1)
-    counts_col = incl[:, e - 1:e]                    # [NB, 1]
+    counts_col = jnp.sum(oh, axis=1, keepdims=True)  # [NB, 1]
     sel = (_iota((nb, e), 0) == jnp.clip(bid, 0, nb - 1)).astype(jnp.int32)
-    slot = jnp.sum((incl - oh) * sel, axis=0, keepdims=True)  # [1, E]
+    slot = jnp.sum(exclusive_cumsum(oh) * sel, axis=0, keepdims=True)  # [1, E]
 
     keep = valid & (slot < capacity)
     overflow = count(valid & (slot >= capacity))
@@ -254,7 +253,7 @@ def _lif_kernel(
         # Dense-mask event front-end: lane order == from_spikes compaction
         # order; the FPGA-interface truncation is the rank cut.
         s32 = spk.astype(jnp.int32)
-        rank = jnp.cumsum(s32, axis=1) - s32
+        rank = exclusive_cumsum(s32)
         evalid = s32 * (rank < event_capacity).astype(jnp.int32)
         now_k = t0 + k
         slab_col, counts_col, traffic_col, stats_col = _inject_substep(

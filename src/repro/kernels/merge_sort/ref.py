@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-_INF = jnp.int32(2**30)
+_INF = 2**30
 
 
 def merge_sort_ref(

@@ -23,10 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_D = 128
 BLOCK_T = 128
@@ -78,17 +75,11 @@ def ssm_scan_pallas(x, dt, A, Bm, Cm, D, *, interpret: bool = False,
     y_spec = pl.BlockSpec((1, block_t, block_d),
                           lambda bi, di, ti: (bi, ti, di))
 
-    scratch = None
+    scratch = [pltpu.VMEM((block_d, n), jnp.float32)]
     kwargs = {}
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_d, n), jnp.float32)]
-        cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cp_cls is not None and not interpret:
-            kwargs["compiler_params"] = cp_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     kernel = functools.partial(_kernel, block_t=block_t)
     f32 = lambda z: z.astype(jnp.float32)

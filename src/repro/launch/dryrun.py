@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves, without hardware:
@@ -18,7 +15,7 @@ Usage:
 
 import argparse
 import json
-import re
+import os
 import time
 import traceback
 
@@ -27,7 +24,7 @@ import jax.numpy as jnp
 
 from repro import configs as C
 from repro.launch import hlo_stats
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.models import lm
 from repro.models import sharding as shd
 from repro.optim import adamw, schedules
@@ -221,13 +218,7 @@ def lower_snn(n_chips: int, mode: str = "simplified",
     import dataclasses as _dc
 
     import numpy as np
-    try:
-        from jax import shard_map
-        _rep_kw = {"check_vma": False}
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-        _rep_kw = {"check_rep": False}
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import Mesh
 
     from repro.configs.bss2 import CONFIG as BSS2
     from repro.core import delays as dl
@@ -250,11 +241,9 @@ def lower_snn(n_chips: int, mode: str = "simplified",
         mesh = Mesh(np.asarray(devices[:n_chips]).reshape(n_pods, cpp),
                     ("pod", "chip"))
         axis: str | tuple = ("pod", "chip")
-        shard_axes = ("pod", "chip")
     else:
         mesh = Mesh(np.asarray(devices[:n_chips]), ("chip",))
         axis = "chip"
-        shard_axes = "chip"
     comm = _dc.replace(BSS2.comm, n_chips=n_chips, mode=mode,
                        merge_rate=merge_rate)
     cfg = net.NetworkConfig(comm=comm, neuron_model=BSS2.neuron_model,
@@ -291,52 +280,8 @@ def lower_snn(n_chips: int, mode: str = "simplified",
         merge=merge_state,
     )
     ext = sds((n_chips, ni), f32)
+    step = net.shard_map_block(cfg, mesh, axis)
 
-    def body(params, state, ext):
-        sq = lambda z: jax.tree.map(lambda a: a[0], z)
-        ex = lambda z: jax.tree.map(lambda a: a[None], z)
-        opt = lambda f, z: None if z is None else f(z)
-        local_state = net.NetworkState(
-            neuron=sq(state.neuron), ring=sq(state.ring), t=state.t,
-            flow=opt(sq, state.flow), merge=opt(sq, state.merge),
-            sendq=opt(sq, state.sendq))
-        new_state, rec = net.shard_step(
-            cfg, axis,
-            net.NetworkParams(crossbar=sq(params.crossbar),
-                              neuron=sq(params.neuron), table=sq(params.table)),
-            local_state, ext[0],
-        )
-        return (
-            net.NetworkState(neuron=ex(new_state.neuron),
-                             ring=ex(new_state.ring), t=new_state.t,
-                             flow=opt(ex, new_state.flow),
-                             merge=opt(ex, new_state.merge),
-                             sendq=opt(ex, new_state.sendq)),
-            ex(rec),
-        )
-
-    chip = P(shard_axes)
-    rep = P()
-    param_specs = net.NetworkParams(
-        crossbar=jax.tree.map(lambda _: chip, params.crossbar),
-        neuron=jax.tree.map(lambda _: chip, params.neuron),
-        table=jax.tree.map(lambda _: chip, params.table),
-    )
-    state_specs = net.NetworkState(
-        neuron=jax.tree.map(lambda _: chip, state.neuron),
-        ring=dl.DelayRing(ring=chip, now=chip),
-        t=rep,
-        merge=None if merge_state is None
-        else jax.tree.map(lambda _: chip, merge_state),
-    )
-    step = shard_map(
-        body, mesh=mesh,
-        in_specs=(param_specs, state_specs, chip),
-        out_specs=(state_specs, jax.tree.map(lambda _: chip,
-                                             net.StepRecord(spikes=0, voltage=0,
-                                                            stats=_stats_proto(c)))),
-        **_rep_kw,
-    )
     t0 = time.time()
     with mesh:
         lowered = jax.jit(step).lower(params, state, ext)
@@ -368,14 +313,6 @@ def lower_snn(n_chips: int, mode: str = "simplified",
             "collective_total": stats.total_collective_bytes,
         },
     }
-
-
-def _stats_proto(c):
-    from repro.core import pulse_comm as pc
-
-    return pc.CommStats(sent=0, overflow=0, merge_dropped=0, expired=0,
-                        stalled=0, utilization=0, wire_bytes=0, traffic=0,
-                        link_words=0, link_backlog=0, lost_to_failure=0)
 
 
 # Per-arch optimized variants discovered by the §Perf hillclimbing
@@ -413,6 +350,7 @@ def main() -> None:
                     help="with --snn: only the 512-chip (pod x chip) cell "
                          "(the CI fault-drill smoke)")
     args = ap.parse_args()
+    force_host_devices(512)
 
     if args.snn:
         from repro.core import topology as tpo

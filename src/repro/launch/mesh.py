@@ -1,9 +1,9 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
-never touches jax device state — the dry-run must set
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init,
-and smoke tests must keep seeing 1 device.
+never touches jax device state — the dry-run's ``main`` asks for 512 host
+devices (:func:`force_host_devices`) before first jax init, and smoke tests
+must keep seeing 1 device.
 
 Topology: TPU v5e pods, 16x16 = 256 chips per pod on the ICI torus;
 multi-pod adds a leading "pod" axis over DCN.  (The Extoll analogue: the
@@ -13,9 +13,19 @@ paper's 3D torus; the "pod" axis is the inter-wafer-module tier.)
 from __future__ import annotations
 
 import math
+import os
 
 import jax
 import numpy as np
+
+
+def force_host_devices(n: int) -> None:
+    """Ask XLA's CPU backend for ``n`` virtual devices, keeping whatever
+    ``XLA_FLAGS`` the caller set.  Entry points call this before JAX
+    first touches a device."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS", ""), flag) if f)
 
 
 def make_production_mesh(*, multi_pod: bool = False,

@@ -94,7 +94,7 @@ def make_compressed_step(cfg, mesh, *, peak_lr, total_steps,
         local_step, mesh=mesh,
         in_specs=(state_spec, batch_spec, rep),
         out_specs=(state_spec, rep),
-        check_rep=False,
+        check_vma=False,
     )
 
 

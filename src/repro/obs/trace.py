@@ -8,8 +8,7 @@ lightweight host-side span timer for benchmark drivers.
   It adds *metadata only*: op counts, scheduling, and numerics are
   untouched, so the one-collective-per-block HLO pins keep holding.
 * :class:`jax.profiler.TraceAnnotation` — marks the host timeline when
-  a profiler session is active; a silent no-op otherwise.  Guarded so
-  an absent/changed profiler API can never break the hot path.
+  a profiler session is active; a silent no-op otherwise.
 """
 
 from __future__ import annotations
@@ -24,12 +23,7 @@ import jax
 @contextlib.contextmanager
 def phase_scope(name: str) -> Iterator[None]:
     """Annotate a fabric phase for device + host profiles (no-op cost)."""
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(jax.named_scope(name))
-        try:
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
-        except Exception:
-            pass  # profiling unavailable — tracing must never break the run
+    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
         yield
 
 

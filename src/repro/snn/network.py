@@ -28,7 +28,9 @@ single-device form (:func:`step` / :func:`run` / :func:`run_plastic` —
 leading chip axis, fabric transport "local") and the shard_map production
 form (:func:`shard_step` — chips = mesh shards, real ICI collectives).
 The two differ only in the fabric binding and whether per-chip functions
-run under ``jax.vmap``.
+run under ``jax.vmap``.  :func:`shard_run` drives the shard form over a
+device mesh (:func:`shard_map_block`) with :func:`run`'s arguments and
+results.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import delays as dl
 from repro.core import events as ev
@@ -117,6 +120,9 @@ class StepRecord(NamedTuple):
     spikes: jax.Array            # [n_chips, n_neurons] (f32 0/1)
     voltage: jax.Array           # [n_chips, n_neurons]
     stats: pc.CommStats
+    # Events popped from the delay ring this step — with the final ring
+    # contents, the delivered leg of the conservation identity.
+    delivered: jax.Array         # [n_chips] int32
 
 
 def _neuron_fns(cfg: NetworkConfig):
@@ -349,8 +355,14 @@ def _step_impl(
     new_state = NetworkState(neuron=nstate, ring=ring, t=state.t + 1,
                              flow=flow, merge=merge, sendq=sendq,
                              metrics=metrics)
-    rec = StepRecord(spikes=spikes, voltage=voltage, stats=stats)
+    rec = StepRecord(spikes=spikes, voltage=voltage, stats=stats,
+                     delivered=_popped(in_spikes))
     return new_state, rec, new_w, new_stdp
+
+
+def _popped(in_spikes: jax.Array) -> jax.Array:
+    """Events popped from the ring per chip (``in_spikes`` [..., n_inputs])."""
+    return jnp.sum(in_spikes, axis=-1).astype(jnp.int32)
 
 
 def _superstep_active(cfg: NetworkConfig) -> bool:
@@ -430,10 +442,11 @@ def _block_impl(
         ring = vm(dl.tick)(ring)
         voltage = (nstate.v if cfg.record_voltage
                    else jnp.zeros_like(nstate.v))
-        return (nstate, ring, t + 1, new_w, new_stdp), (ebs, spikes, voltage)
+        return ((nstate, ring, t + 1, new_w, new_stdp),
+                (ebs, spikes, voltage, _popped(in_spikes)))
 
     carry0 = (state.neuron, state.ring, state.t, w, stdp_state)
-    (nstate, ring, _, new_w, new_stdp), (ebs, spikes, voltage) = \
+    (nstate, ring, _, new_w, new_stdp), (ebs, spikes, voltage, popped) = \
         jax.lax.scan(substep, carry0, ext_block)
 
     # Flush the block through the fabric at the block-start clock (the
@@ -458,7 +471,8 @@ def _block_impl(
                              flow=res.flow, merge=res.merge,
                              sendq=res.sendq, pending=res.pending,
                              metrics=metrics)
-    rec = StepRecord(spikes=spikes, voltage=voltage, stats=res.stats)
+    rec = StepRecord(spikes=spikes, voltage=voltage, stats=res.stats,
+                     delivered=popped)
     return new_state, rec, new_w, new_stdp
 
 
@@ -746,3 +760,93 @@ def shard_flush_pending(
     new_state = state._replace(ring=res.ring, merge=res.merge,
                                pending=res.pending)
     return new_state, res.stats
+
+
+# ---------------------------------------------------------------------------
+# The per-shard bodies as one shard_map: one simulated chip per device
+# ---------------------------------------------------------------------------
+
+_REPLICATED_STATE = ("t", "metrics")
+
+
+def _map_state(state: NetworkState, on_chip, on_rep) -> NetworkState:
+    """Apply ``on_chip`` to the chip-local carries and ``on_rep`` to the
+    replicated fields (the clock and the fleet telemetry); None stays."""
+    return NetworkState(**{
+        k: None if v is None else (on_rep if k in _REPLICATED_STATE
+                                   else on_chip)(v)
+        for k, v in state._asdict().items()})
+
+
+def shard_map_block(
+    cfg: NetworkConfig,
+    mesh: jax.sharding.Mesh,
+    axis: str | tuple[str, ...] = "chip",
+):
+    """:func:`shard_step` (B=1) or :func:`shard_superstep` (B>1) wrapped
+    in one ``jax.shard_map`` over ``axis`` of ``mesh``.
+
+    The returned ``fn(params, state, ext)`` speaks the chip-stacked layout
+    of the single-device forms: ``params`` and the chip-local carries of
+    ``state`` lead with the chip axis (``state.t`` is replicated), ``ext``
+    is ``[n_chips, n_inputs]`` (B=1) or ``[n_chips, B, n_inputs]``, and
+    every record leaf leads with the chip axis (``[n_chips, B, ...]`` for
+    B>1).  One simulated chip runs per device; the fabric's exchange is a
+    real collective over ``axis``.
+    """
+    if _pipeline_active(cfg):
+        raise ValueError(
+            "the pipelined schedule is driven shard-locally through "
+            "shard_pipeline_block / shard_flush_pending")
+    per_shard = shard_superstep if _superstep_active(cfg) else shard_step
+    chip, rep = P(axis), P()
+    squeeze = lambda z: jax.tree.map(lambda a: a[0], z)
+    expand = lambda z: jax.tree.map(lambda a: a[None], z)
+    keep = lambda z: z
+
+    def body(params, state, ext):
+        new_state, rec = per_shard(cfg, axis, squeeze(params),
+                                   _map_state(state, squeeze, keep), ext[0])
+        return _map_state(new_state, expand, keep), expand(rec)
+
+    def fn(params, state, ext):
+        state_specs = _map_state(state, lambda _: chip, lambda _: rep)
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(chip, state_specs, chip),
+            out_specs=(state_specs, chip), check_vma=False,
+        )(params, state, ext)
+
+    return fn
+
+
+def shard_run(
+    cfg: NetworkConfig,
+    mesh: jax.sharding.Mesh,
+    params: NetworkParams,
+    state: NetworkState,
+    ext_inputs: jax.Array,        # [T, n_chips, n_inputs]
+) -> tuple[NetworkState, StepRecord]:
+    """:func:`run` with one simulated chip per device of ``mesh`` (its
+    ``"chip"`` axis).
+
+    Same arguments, results and schedule as :func:`run` (chip-stacked
+    params and state, records ``[T, n_chips, ...]``), bitwise-equal to it
+    in every integer leaf.  The membrane voltages may differ in the last
+    bits on the CPU backend, where the crossbar matmul batched over chips
+    on one device rounds differently from the per-chip one here; on a TPU
+    v5e they are bit-equal too.  The exchange runs as real collectives —
+    ``all_to_all`` on the dense transport, hop-by-hop ``ppermute``
+    forwarding when ``cfg.topology`` is set.
+    """
+    state = _ensure_carries(local_fabric(cfg), state)
+    block = shard_map_block(cfg, mesh, "chip")
+    blocked = _superstep_active(cfg)
+    xs = (_blocked_inputs(cfg, ext_inputs).swapaxes(1, 2) if blocked
+          else ext_inputs)                 # [T/B, n_chips, B, n_inputs]
+    final, recs = jax.lax.scan(lambda s, x: block(params, s, x), state, xs)
+    if blocked:
+        recs = jax.tree.map(
+            lambda r: r.swapaxes(1, 2).reshape(
+                (r.shape[0] * r.shape[2], r.shape[1]) + r.shape[3:]),
+            recs)
+    return final, recs

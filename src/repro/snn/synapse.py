@@ -47,8 +47,13 @@ def init_crossbar(
 
 
 def currents(crossbar: Crossbar, input_spikes: jax.Array) -> jax.Array:
-    """Dense delivery: spike counts [*, n_inputs] -> currents [*, n_neurons]."""
-    return input_spikes.astype(crossbar.w.dtype) @ crossbar.w
+    """Dense delivery: spike counts [*, n_inputs] -> currents [*, n_neurons].
+
+    ``HIGHEST`` keeps the matmul at f32 on a TPU, whose default precision
+    is one bf16 pass, so a TPU run computes the f32 currents of a CPU run
+    up to summation order."""
+    return jnp.matmul(input_spikes.astype(crossbar.w.dtype), crossbar.w,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @jax.custom_vjp

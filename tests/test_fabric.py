@@ -412,7 +412,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt, transport as tp
@@ -463,7 +463,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
 
         specs = (P("chip"),) * 6
         got = shard_map(body, mesh=mesh, in_specs=specs,
-                        out_specs=P("chip"), check_rep=False)(
+                        out_specs=P("chip"), check_vma=False)(
             ebs, tables, rings, flow_b, merge_b, sendq_b)
 
         np.testing.assert_array_equal(np.asarray(got.ring.ring),

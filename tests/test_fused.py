@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro import obs
 from repro.core import delays as dl
@@ -232,9 +233,9 @@ def _count_pallas_calls(jaxpr) -> int:
     """pallas_call equations in a jaxpr, nested scopes (pjit /
     closed_call / scan / custom_* bodies) included."""
     def subs(v):
-        if isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, Jaxpr):
             yield v
-        elif isinstance(v, jax.core.ClosedJaxpr):
+        elif isinstance(v, ClosedJaxpr):
             yield v.jaxpr
         elif isinstance(v, (list, tuple)):
             for x in v:

@@ -13,6 +13,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from jax.experimental import pallas as pl
+
+from repro.kernels import common
 from repro.kernels.bucket_pack import bucket_pack
 from repro.kernels.bucket_pack.ref import bucket_pack_ref
 from repro.kernels.flash_attention import flash_attention
@@ -23,6 +26,38 @@ from repro.kernels.merge_sort import merge_sort
 from repro.kernels.merge_sort.ref import merge_sort_ref
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
+
+
+def test_resolve_interpret_off_tpu(monkeypatch):
+    monkeypatch.delenv(common.FORCE_INTERPRET_ENV, raising=False)
+    assert common.resolve_interpret(None) is True
+    assert common.resolve_interpret(False) is False
+    monkeypatch.setenv(common.FORCE_INTERPRET_ENV, "1")
+    assert common.resolve_interpret(False) is True
+
+
+@pytest.mark.parametrize("interpret,forced", [(True, ""), (None, "1"),
+                                              (False, "1")])
+def test_resolve_interpret_never_interprets_on_tpu(monkeypatch, interpret,
+                                                   forced):
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    monkeypatch.setenv(common.FORCE_INTERPRET_ENV, forced)
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        common.resolve_interpret(interpret)
+    monkeypatch.setenv(common.FORCE_INTERPRET_ENV, "")
+    assert common.resolve_interpret(None) is False
+
+
+@pytest.mark.parametrize("rows,lanes", [(1, 128), (3, 512), (46, 2048)])
+def test_exclusive_cumsum_matches_jnp(rows, lanes):
+    x = jax.random.randint(jax.random.PRNGKey(lanes), (rows, lanes), 0, 3)
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = common.exclusive_cumsum(x_ref[...])
+
+    got = pl.pallas_call(kernel, out_shape=x, interpret=True)(x)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.cumsum(x, axis=1) - x))
 
 
 @pytest.mark.parametrize("e,b,c", [(64, 2, 4), (512, 8, 16), (777, 5, 8),

@@ -1,10 +1,17 @@
 import dataclasses
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.configs.bss2 import CONFIG as BSS2
 from repro.core import pulse_comm as pc
 from repro.core import routing as rt
 from repro.snn import network as net
@@ -67,20 +74,21 @@ def test_event_path_matches_dense_path():
     np.testing.assert_array_equal(outs["event"], outs["dense"])
 
 
+def _delivered(final, rec):
+    """Events that reached a ring: popped during the run + still queued."""
+    return int(np.asarray(rec.delivered).sum()) + int(
+        np.asarray(final.ring.ring).sum())
+
+
 def test_overflow_loses_spikes_but_accounts_them():
     cfg, params, state, ext = _ff_network(capacity=8)  # 32 spikes/step, cap 8
-    _, rec = net.run(cfg, params, state, ext)
+    final, rec = net.run(cfg, params, state, ext)
     assert int(rec.stats.overflow.sum()) > 0
-    sent = int(rec.stats.sent.sum())
-    of = int(rec.stats.overflow.sum())
-    exp = int(rec.stats.expired.sum())
-    # delivered = all spikes that made it into chip-1 activity via ring;
-    # conservation checked per step inside pulse_comm tests; here just
-    # verify the target chip fired strictly less than in the ample case
+    obs.check_conservation(rec.stats, delivered=_delivered(final, rec))
+    # the target chip fires strictly less than in the ample case
     cfg2, p2, s2, e2 = _ff_network()
     _, rec2 = net.run(cfg2, p2, s2, e2)
     assert rec.spikes[:, 1].sum() < rec2.spikes[:, 1].sum()
-    assert sent - of - exp >= 0
 
 
 def test_adex_network_runs():
@@ -122,3 +130,51 @@ def test_surrogate_training_reduces_loss():
         w = w - 5.0 * jax.grad(loss_fn)(w)
     l1 = float(loss_fn(w))
     assert l1 < l0
+
+
+@pytest.mark.parametrize("B,mode,merge_rate", [(1, "simplified", 0),
+                                               (4, "simplified", 0),
+                                               (4, "full", 8)])
+def test_run_records_close_event_conservation(B, mode, merge_rate):
+    """Σ sent == popped + left in the rings + queued + every drop leg, over
+    a whole run of the reduced wafer module."""
+    comm = dataclasses.replace(BSS2.reduced().comm, superstep=B, mode=mode,
+                               merge_rate=merge_rate)
+    cfg = net.NetworkConfig(comm=comm, neuron_model="adex")
+    params = net.init_params(jax.random.PRNGKey(0), cfg)
+    ext = (jax.random.uniform(jax.random.PRNGKey(1),
+                              (16, comm.n_chips, comm.n_inputs_per_chip))
+           < 0.1).astype(jnp.float32)
+    final, rec = net.run(cfg, params, net.init_state(cfg, params), ext)
+    queued = 0 if final.merge is None else final.merge.occupancy()
+    rep = obs.check_conservation(rec.stats, delivered=_delivered(final, rec),
+                                 queued=queued)
+    assert rep.delivered > 0 and rep.injected > rep.delivered
+
+
+_SHARD_RUN_SCRIPT = textwrap.dedent("""
+    import dataclasses
+    import sys
+    import chip_smoke
+    from repro.configs.bss2 import CONFIG
+    comm = dataclasses.replace(CONFIG.reduced().comm, superstep=4)
+    failures = chip_smoke.four_chips(comm, seed=0, steps=16,
+                                     float_tol=chip_smoke.CPU_FLOAT_TOL)
+    assert not failures, failures
+    print("SHARD_RUN_OK")
+""")
+
+
+def test_shard_run_matches_run_on_four_devices():
+    """chip_smoke.py --chips 4 at reduced widths on four virtual CPU
+    devices: shard_run on the dense and torus2d(2, 2) transports equals
+    run on one device (float leaves within the CPU bound), with outputs
+    spread over the mesh."""
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(root), str(root / "src")]))
+    out = subprocess.run([sys.executable, "-c", _SHARD_RUN_SCRIPT],
+                         capture_output=True, text=True, timeout=600,
+                         env=env, cwd=root)
+    assert "SHARD_RUN_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-3000:]

@@ -451,7 +451,7 @@ _HLO_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt
@@ -496,7 +496,7 @@ _HLO_SCRIPT = textwrap.dedent("""
         in_specs=(P(None, "chip"), P("chip"), P("chip"), P("chip")),
         out_specs=(P("chip"), P(None, "chip"), P(None, "chip"),
                    P("chip")),
-        check_rep=False)
+        check_vma=False)
     compiled = jax.jit(f).lower(ebs, tables, rings, pending).compile()
     counts = hlo_stats.count_collectives(compiled)
     assert hlo_stats.count_collectives(compiled, "all-to-all") == 1, counts

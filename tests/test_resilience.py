@@ -536,7 +536,7 @@ _DEGRADED_SHARD_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt, topology as tpo
@@ -570,7 +570,7 @@ _DEGRADED_SHARD_SCRIPT = textwrap.dedent("""
             return jax.tree.map(lambda a: a[None] if hasattr(a, "ndim")
                                 else a, out)
         got = shard_map(body, mesh=mesh, in_specs=(P("chip"),) * 3,
-                        out_specs=P("chip"), check_rep=False)(
+                        out_specs=P("chip"), check_vma=False)(
             ebs, tables, rings)
 
         np.testing.assert_array_equal(np.asarray(got.ring.ring),
@@ -602,7 +602,7 @@ _POD_MESH_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import events as ev, topology as tpo
 
@@ -630,7 +630,7 @@ _POD_MESH_SCRIPT = textwrap.dedent("""
         out = tr.exchange_words(jax.tree.map(lambda a: a[0], s))
         return jax.tree.map(lambda a: a[None], out)
     got = shard_map(body, mesh=mesh, in_specs=P(("pod", "chip")),
-                    out_specs=P(("pod", "chip")), check_rep=False)(x)
+                    out_specs=P(("pod", "chip")), check_vma=False)(x)
 
     for a, b in zip(got, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

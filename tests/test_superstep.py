@@ -159,7 +159,7 @@ _HLO_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt
@@ -207,7 +207,7 @@ _HLO_SCRIPT = textwrap.dedent("""
                 ring=P("chip"), delivered=P(None, "chip"),
                 stats=P(None, "chip"), flow=None,
                 merge=P("chip") if merge_rate else None, sendq=None),
-            check_rep=False)
+            check_vma=False)
         compiled = jax.jit(f).lower(ebs, tables, rings, merge_b).compile()
         counts = hlo_stats.count_collectives(compiled)
         count = hlo_stats.count_collectives(compiled, "all-to-all")

@@ -351,7 +351,7 @@ _SHARD_EQUIV_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt, topology as tpo
@@ -382,7 +382,7 @@ _SHARD_EQUIV_SCRIPT = textwrap.dedent("""
             return jax.tree.map(lambda a: a[None] if hasattr(a, "ndim")
                                 else a, out)
         got = shard_map(body, mesh=mesh, in_specs=(P("chip"),) * 3,
-                        out_specs=P("chip"), check_rep=False)(
+                        out_specs=P("chip"), check_vma=False)(
             ebs, tables, rings)
 
         np.testing.assert_array_equal(np.asarray(got.ring.ring),

@@ -83,7 +83,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import transport as tp
 
@@ -131,7 +131,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     f2 = shard_map(shard_body, mesh=mesh,
                    in_specs=(P("chip"), P("chip"), P("chip")),
                    out_specs=(P("chip"), P("chip")),
-                   check_rep=False)
+                   check_vma=False)
     got_rings, got_stats = f2(ebs, tables, rings)
     np.testing.assert_array_equal(np.asarray(got_rings.ring),
                                   np.asarray(ref_rings.ring))
@@ -158,7 +158,7 @@ _HIERARCHICAL_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import transport as tp
 
@@ -194,7 +194,7 @@ _THREE_AXIS_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import transport as tp
 

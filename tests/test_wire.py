@@ -258,7 +258,7 @@ _HLO_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt
@@ -294,7 +294,7 @@ _HLO_SCRIPT = textwrap.dedent("""
                                 else a, out)
 
         f = shard_map(body, mesh=mesh, in_specs=(P("chip"),) * 4,
-                      out_specs=P("chip"), check_rep=False)
+                      out_specs=P("chip"), check_vma=False)
         compiled = jax.jit(f).lower(ebs, tables, rings, merge_b).compile()
         counts = hlo_stats.count_collectives(compiled)
         count = hlo_stats.count_collectives(compiled, "all-to-all")
