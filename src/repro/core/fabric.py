@@ -585,7 +585,8 @@ class PulseFabric:
             now_k = t0 + k
             defer_k = (b - 1) - k
             events_k = jax.tree.map(lambda x: x[k], events)
-            routed = rt.route(events_k, table)
+            with phase_scope("fabric/inject/route"):
+                routed = rt.route(events_k, table)
             # ``sent`` counts each substep's fresh stream only — a queued
             # event was counted when first offered, so run-level
             # conservation reads
@@ -624,8 +625,9 @@ class PulseFabric:
             wrap_expired = jnp.sum(
                 routed.valid & ~in_window).astype(jnp.int32)
             routed = routed._replace(valid=routed.valid & in_window)
-            flushbuf, counts, overflow, traffic = pc.aggregate_into(
-                cfg, routed, flushbuf, k)
+            with phase_scope("fabric/inject/pack"):
+                flushbuf, counts, overflow, traffic = pc.aggregate_into(
+                    cfg, routed, flushbuf, k)
 
             stalled = jnp.int32(0)
             if self.flow is not None:

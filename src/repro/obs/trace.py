@@ -1,14 +1,16 @@
-"""Phase tracing: jit-safe named scopes for device profiles plus a
-lightweight host-side span timer for benchmark drivers.
+"""Phase tracing: one annotation per context.
 
-``phase_scope(name)`` stacks two annotations:
-
-* :func:`jax.named_scope` — threads the name into XLA op metadata so a
-  device profile (or an HLO dump) attributes time to fabric stages.
-  It adds *metadata only*: op counts, scheduling, and numerics are
-  untouched, so the one-collective-per-block HLO pins keep holding.
-* :class:`jax.profiler.TraceAnnotation` — marks the host timeline when
-  a profiler session is active; a silent no-op otherwise.
+* :func:`phase_scope` is :func:`jax.named_scope`, for traced (jitted)
+  code: it threads the name into the op metadata of every op traced
+  under it, so a device profile (or an HLO dump) attributes time to
+  fabric and network stages.  It adds *metadata only*: op counts,
+  scheduling and numerics are untouched, so the one-collective-per-block
+  HLO pins keep holding.  Under ``jit`` it leaves nothing on the host
+  timeline: the function body runs only while JAX traces it.
+* :class:`SpanTimer` times host-side spans (staging, dispatch,
+  ``block_until_ready`` boundaries) and marks each on the host timeline
+  with :class:`jax.profiler.TraceAnnotation`, a silent no-op unless a
+  profiler session is active.
 """
 
 from __future__ import annotations
@@ -20,11 +22,9 @@ from typing import Iterator
 import jax
 
 
-@contextlib.contextmanager
-def phase_scope(name: str) -> Iterator[None]:
-    """Annotate a fabric phase for device + host profiles (no-op cost)."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+def phase_scope(name: str) -> contextlib.AbstractContextManager[None]:
+    """Name a phase of traced code in the op metadata (no run-time cost)."""
+    return jax.named_scope(name)
 
 
 class SpanTimer:
@@ -42,7 +42,7 @@ class SpanTimer:
     def span(self, name: str) -> Iterator[None]:
         t0 = time.perf_counter()
         try:
-            with phase_scope(name):
+            with jax.profiler.TraceAnnotation(name):
                 yield
         finally:
             dt_ms = (time.perf_counter() - t0) * 1e3

@@ -43,6 +43,8 @@ import dataclasses
 import time
 from typing import Any, Callable, NamedTuple
 
+import jax
+
 from repro import checkpoint as ckpt
 
 
@@ -211,11 +213,11 @@ class ResilientRunner:
         if (self.flight_of is None or self.flight_dir is None
                 or self._last_state is None):
             return
-        from repro.obs import dump_flight, phase_scope
+        from repro.obs import dump_flight
         flight = self.flight_of(self._last_state)
         if flight is None:
             return
-        with phase_scope("fabric/recovery_dump"):
+        with jax.profiler.TraceAnnotation("fabric/recovery_dump"):
             path = (f"{self.flight_dir}/flight_{failure.step:06d}"
                     f"_{len(self.flight_dumps)}.jsonl")
             dump_flight(path, flight, recoveries=self.recoveries,

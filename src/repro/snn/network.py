@@ -277,6 +277,29 @@ def _zero_stats(c: pc.PulseCommConfig) -> pc.CommStats:
 # The ONE step body
 # ---------------------------------------------------------------------------
 
+def _dynamics(vm, nstep, neuron_params, w, nstate, ring, ext):
+    """Pop the delay ring, add the external input, drive the crossbar and
+    step the neurons — each part under its own scope (``snn/ring``,
+    ``snn/synapse``, ``snn/neuron``), so a device profile names it.
+
+    Returns (ring, in_spikes, total_in, nstate, spikes)."""
+    with phase_scope("snn/ring"):
+        ring, in_spikes = vm(dl.pop_current)(ring)
+    total_in = in_spikes.astype(jnp.float32) + ext
+    with phase_scope("snn/synapse"):
+        currents = vm(sy.currents)(sy.Crossbar(w=w), total_in)
+    with phase_scope("snn/neuron"):
+        nstate, spikes = vm(nstep)(nstate, currents, neuron_params)
+    return ring, in_spikes, total_in, nstate, spikes
+
+
+def _events(vm, spikes: jax.Array, t: jax.Array,
+            capacity: int) -> ev.EventBuffer:
+    """Compact each chip's spikes into its event buffer (``snn/spikes``)."""
+    with phase_scope("snn/spikes"):
+        return vm(lambda s: ev.from_spikes(s > 0.5, t, capacity)[0])(spikes)
+
+
 def _step_impl(
     cfg: NetworkConfig,
     fabric: fb.PulseFabric,
@@ -312,10 +335,8 @@ def _step_impl(
     nstep, _ = _neuron_fns(cfg)
     vm = jax.vmap if fabric.batched else (lambda f: f)
 
-    ring, in_spikes = vm(dl.pop_current)(state.ring)
-    total_in = in_spikes.astype(jnp.float32) + ext_input
-    currents = vm(sy.currents)(sy.Crossbar(w=w), total_in)
-    nstate, spikes = vm(nstep)(state.neuron, currents, neuron_params)
+    ring, in_spikes, total_in, nstate, spikes = _dynamics(
+        vm, nstep, neuron_params, w, state.neuron, state.ring, ext_input)
 
     new_stdp, new_w = stdp_state, w
     if stdp_cfg is not None:
@@ -341,14 +362,13 @@ def _step_impl(
         ring = dense_route(c, spikes, table, ring, state.t)
         stats = _zero_stats(c)
     else:
-        t = state.t
-        ebs = vm(lambda s: ev.from_spikes(s > 0.5, t, c.event_capacity)[0])(
-            spikes)
+        ebs = _events(vm, spikes, state.t, c.event_capacity)
         res = fabric.step(ebs, table, ring, flow, merge, sendq)
         ring, stats = res.ring, res.stats
         flow, merge, sendq = res.flow, res.merge, res.sendq
 
-    ring = vm(dl.tick)(ring)
+    with phase_scope("snn/ring"):
+        ring = vm(dl.tick)(ring)
     voltage = nstate.v if cfg.record_voltage else jnp.zeros_like(nstate.v)
     metrics = _metrics_update(cfg, fabric, state.metrics, stats,
                               merge=merge)
@@ -425,10 +445,8 @@ def _block_impl(
 
     def substep(carry, ext):
         nstate, ring, t, w_, stdp_ = carry
-        ring, in_spikes = vm(dl.pop_current)(ring)
-        total_in = in_spikes.astype(jnp.float32) + ext
-        currents = vm(sy.currents)(sy.Crossbar(w=w_), total_in)
-        nstate, spikes = vm(nstep)(nstate, currents, neuron_params)
+        ring, in_spikes, total_in, nstate, spikes = _dynamics(
+            vm, nstep, neuron_params, w_, nstate, ring, ext)
         new_stdp, new_w = stdp_, w_
         if stdp_cfg is not None:
             from repro.snn import stdp as stdp_mod
@@ -437,9 +455,9 @@ def _block_impl(
                 lambda s, pre, post, ww: stdp_mod.step(stdp_cfg, s, pre,
                                                        post, ww)
             )(stdp_, total_in, spikes, w_)
-        ebs = vm(lambda s: ev.from_spikes(s > 0.5, t, c.event_capacity)[0])(
-            spikes)
-        ring = vm(dl.tick)(ring)
+        ebs = _events(vm, spikes, t, c.event_capacity)
+        with phase_scope("snn/ring"):
+            ring = vm(dl.tick)(ring)
         voltage = (nstate.v if cfg.record_voltage
                    else jnp.zeros_like(nstate.v))
         return ((nstate, ring, t + 1, new_w, new_stdp),

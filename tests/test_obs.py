@@ -269,3 +269,77 @@ def test_monitor_demo_and_check(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "conservation identity" in out
     assert "drop buckets" in out
+
+
+# ---------------------------------------------------------------------------
+# Phase scopes: names in the compiled program, one annotation per context
+# ---------------------------------------------------------------------------
+
+NETWORK_SCOPES = ("snn/ring", "snn/synapse", "snn/neuron", "snn/spikes",
+                  "fabric/inject/route", "fabric/inject/pack")
+
+
+@pytest.mark.parametrize("superstep", [1, 4], ids=["B1", "B4"])
+def test_network_scopes_reach_the_optimized_hlo(superstep):
+    """Both execution forms (the per-step body and the blocked substep)
+    name ring, crossbar, neuron, compaction, route and pack in the op
+    metadata that survives XLA's optimization."""
+    comm = pc.PulseCommConfig(
+        n_chips=2, neurons_per_chip=8, n_inputs_per_chip=8,
+        event_capacity=8, bucket_capacity=8, ring_depth=16, fanout=2,
+        superstep=superstep)
+    cfg = net.NetworkConfig(comm=comm, neuron_model="adex")
+    params = net.init_params(jax.random.PRNGKey(3), cfg)
+    state = net.init_state(cfg, params)
+    ext = jnp.zeros((4, 2, 8))
+    hlo = jax.jit(lambda p, s, e: net.run(cfg, p, s, e)).lower(
+        params, state, ext).compile().as_text()
+    op_names = "\n".join(
+        line.split('op_name="', 1)[1].split('"', 1)[0]
+        for line in hlo.splitlines() if 'op_name="' in line)
+    for scope in NETWORK_SCOPES:
+        assert scope in op_names, scope
+
+
+class _Annotations:
+    """Counts ``jax.profiler.TraceAnnotation``s made while installed."""
+
+    def __init__(self, monkeypatch):
+        self.names = []
+        real = jax.profiler.TraceAnnotation
+
+        def record(name, **kwargs):
+            self.names.append(name)
+            return real(name, **kwargs)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", record)
+
+
+def test_phase_scope_names_ops_and_leaves_no_host_annotation(monkeypatch):
+    seen = _Annotations(monkeypatch)
+
+    @jax.jit
+    def f(x):
+        with obs.phase_scope("snn/neuron"):
+            return jnp.sin(x) * 2.0
+
+    x = jnp.arange(4.0)
+    assert "snn/neuron" in f.lower(x).as_text(debug_info=True)
+    np.testing.assert_allclose(np.asarray(f(x)), np.sin(np.arange(4.0)) * 2.0,
+                               rtol=1e-6)
+    assert seen.names == []
+
+
+def test_span_timer_counts_and_annotates(monkeypatch):
+    seen = _Annotations(monkeypatch)
+    timer = obs.SpanTimer()
+    for _ in range(3):
+        with timer.span("trial/dispatch"):
+            with timer.span("trial/readback"):
+                pass
+    summary = timer.summary()
+    assert summary["trial/dispatch"]["count"] == 3
+    assert summary["trial/readback"]["count"] == 3
+    assert summary["trial/dispatch"]["total_ms"] >= summary[
+        "trial/readback"]["total_ms"]
+    assert seen.names == ["trial/dispatch", "trial/readback"] * 3
