@@ -11,24 +11,30 @@ sends events.
 
 Phases (one chip, the default):
 
-  (a) the jnp chain (``use_pallas=False``): the reference; the event
-      conservation identity is closed on its totals;
-  (b) the same run with ``use_pallas=True``: ``bucket_pack`` and the
-      fused drain compiled for the chip;
+  (a) the default settings (``use_pallas=False``): on a TPU the fabric
+      injects through the fused inject kernel at any fan-out, and the
+      drain runs the jnp chain; the event conservation identity is
+      closed on its totals;
+  (b) the same run with ``use_pallas=True``: the fused drain compiled
+      for the chip as well;
   (c) full mode with ``merge_rate=8``, ``merge_depth=256``, both ways:
       the fused drain's rate mode (bitonic sort plus the bounded queue);
       and full mode with ``merge_rate=0``: its sort mode;
-  (d) ``fanout=1``, both ways: the fused inject kernel.
+  (d) ``fanout=1``, both ways: the fused inject at the lab setup's
+      fan-out.
 
-Each Pallas run must equal its jnp twin bit for bit — spike trains,
-membrane voltages, delivered counts, ring contents and every
-``CommStats`` field — and its compiled program must hold
-``tpu_custom_call`` (the kernels were compiled, not interpreted).  The
-jnp run of (a) is repeated on the host CPU backend, from the same
-inputs: an independent check of the chip's results.  Its integer leaves
-(spike trains, ring, ``CommStats``) must equal the chip's bit for bit,
-its float leaves (membrane and adaptation state, voltage record) may
-differ by at most ``CPU_FLOAT_TOL``.
+Each ``use_pallas`` run must equal its default twin bit for bit — spike
+trains, membrane voltages, delivered counts, ring contents and every
+``CommStats`` field — and both programs must hold ``tpu_custom_call``
+(the kernels were compiled, not interpreted).  The default run of (a) is
+repeated on the host CPU backend, from the same inputs, where the fabric
+takes the unfused jnp chain (routing gathers, sort-based bucket packing):
+an independent check of the chip's results, and the line (a)
+``matches_chip`` is what pins the fused inject against the jnp chain at
+the wafer module's widths.  Its integer leaves (spike trains, ring,
+``CommStats``) must equal the chip's bit for bit, its float leaves
+(membrane and adaptation state, voltage record) may differ by at most
+``CPU_FLOAT_TOL``.
 
 ``--chips 4`` runs only the path across chips: 4 simulated chips at the
 wafer module's per-chip widths, one per device, through
@@ -174,16 +180,22 @@ def conservation(final, rec):
 
 def run_local(cfg, seed: int, steps: int, device=None):
     """``net.run`` on the local transport; with ``device``, the inputs
-    (made on the default device) are moved there and the run with them."""
+    (made on the default device) are moved there and the run with them,
+    compiled as the default device's (the fabric dispatches for it)."""
+    import contextlib
+
     import jax
 
     from repro.snn import network as net
 
     params = net.init_params(jax.random.PRNGKey(seed), cfg)
     args = (params, net.init_state(cfg, params), drive(seed, cfg.comm, steps))
+    on = contextlib.nullcontext()
     if device is not None:
         args = jax.device_put(args, device)
-    return compile_and_run(functools.partial(net.run, cfg), *args)
+        on = jax.default_device(device)
+    with on:
+        return compile_and_run(functools.partial(net.run, cfg), *args)
 
 
 def report(label: str, out, compile_s: float, run_s: float, steps: int,
@@ -213,12 +225,16 @@ def one_chip(comm, seed: int, steps: int) -> list[str]:
     ]
     for name, c in variants:
         cfg = net.NetworkConfig(comm=c, neuron_model="adex")
-        ref, comp_s, run_s, _ = run_local(cfg, seed, steps)
+        ref, comp_s, run_s, compiled = run_local(cfg, seed, steps)
         rep = conservation(*ref)
         per_chip = np.asarray(ref[1].stats.sent).sum(axis=0)
-        report(f"{name} jnp", ref, comp_s, run_s, steps,
+        kernels = "tpu_custom_call" in compiled.as_text()
+        report(f"{name} default", ref, comp_s, run_s, steps,
                conservation="closed" if rep.ok else f"residual={rep.residual}",
-               chips_sending=f"{int((per_chip > 0).sum())}/{c.n_chips}")
+               chips_sending=f"{int((per_chip > 0).sum())}/{c.n_chips}",
+               tpu_custom_call=kernels)
+        if not kernels:
+            failures.append(f"{name}: no fused inject in the default program")
         if not rep.ok:
             failures.append(f"{name}: conservation residual {rep.residual}")
         if not (per_chip > 0).all():
@@ -231,23 +247,25 @@ def one_chip(comm, seed: int, steps: int) -> list[str]:
         got, comp_s, run_s, compiled = run_local(cfgp, seed, steps)
         bad = mismatches(ref, got)
         kernels = "tpu_custom_call" in compiled.as_text()
-        report(f"{name} pallas", got, comp_s, run_s, steps,
+        report(f"{name} use_pallas", got, comp_s, run_s, steps,
                bit_equal=not bad, tpu_custom_call=kernels)
         if bad:
-            failures.append(f"{name}: pallas differs from jnp in {bad}")
+            failures.append(f"{name}: use_pallas differs from the default "
+                            f"in {bad}")
         if not kernels:
             failures.append(f"{name}: no tpu_custom_call in the program")
     return failures
 
 
 def cross_check_cpu(cfg, seed: int, steps: int, ref) -> list[str]:
-    """The jnp run again on the host CPU backend; returns the failures."""
+    """The default run again on the host CPU backend, where the fabric
+    takes the unfused jnp chain; returns the failures."""
     import jax
 
     got, comp_s, run_s, _ = run_local(cfg, seed, steps,
                                       device=jax.devices("cpu")[0])
     bad = mismatches(ref, got, float_tol=CPU_FLOAT_TOL)
-    report("(a) jnp on the host CPU", got, comp_s, run_s, steps,
+    report("(a) jnp chain on the host CPU", got, comp_s, run_s, steps,
            matches_chip=not bad, float_max_abs_diff=float_diff(ref, got))
     return [f"(a): the CPU run differs from the chip's in {bad}"
             ] if bad else []

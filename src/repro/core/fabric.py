@@ -60,6 +60,7 @@ from repro.core import pulse_comm as pc
 from repro.core import routing as rt
 from repro.core import topology as tpo
 from repro.core import transport as tp
+from repro.kernels import common as kernel_common
 from repro.obs.trace import phase_scope
 
 # Axis name used by the internal vmap of the local path.  Deliberately
@@ -571,12 +572,17 @@ class PulseFabric:
             reach_row = jnp.take(jnp.asarray(self._deliverable),
                                  self.transport.chip_index(), axis=0)
 
-        if cfg.use_pallas and self.flow is None and table.fanout == 1:
-            # Megakernel fast path: the whole B-substep inject chain in a
-            # single pallas_call (repro.kernels.fused_inject), bitwise
-            # equal to the loop below (tests/test_fused.py).  The credit
-            # gate stays host-side (its feedback is sequential across
-            # substeps), so flow-controlled fabrics take the unfused loop.
+        if self.flow is None and (cfg.use_pallas
+                                  or kernel_common.on_tpu()):
+            # The inject path on a TPU, at any fan-out: the whole
+            # B-substep chain in a single pallas_call
+            # (repro.kernels.fused_inject), bitwise equal to the loop
+            # below (tests/test_fused.py), where the loop's batched
+            # gathers, scatters and sorts are latency-bound.  Off the TPU
+            # ``use_pallas`` forces it (interpret mode).  The credit gate
+            # is sequential across substeps, so flow-controlled fabrics
+            # (and the send queue) take the loop, which is also the CPU
+            # path and the kernel's bitwise reference.
             slab, inject = self._inject_block_fused(events, table,
                                                     reach_row, t0)
             return slab, inject, flow, sendq

@@ -66,7 +66,7 @@ class PulseCommConfig:
     merge_rate: int = 0               # full mode: events/step the merge emits
     merge_depth: int = 64             # full mode: merge-queue depth
     time_window: int = 4              # full mode: renaming window (steps)
-    use_pallas: bool = False          # bucket_pack kernel vs jnp reference
+    use_pallas: bool = False          # every Pallas kernel, on any backend
     superstep: int = 1                # B: sim steps batched per exchange
 
     def __post_init__(self):

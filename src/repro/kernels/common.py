@@ -24,6 +24,10 @@ In-kernel building blocks that Mosaic lowers on every TPU generation:
   lane rotations takes its place.
 * :func:`onehot_dot` — the MXU takes no int32 operands; integer one-hot
   contractions run in f32 at ``HIGHEST`` precision, which is exact here.
+* :func:`byte_planes` / :func:`from_byte_planes` — the cheaper exact form
+  of a one-hot contraction: split the integer operand into bytes, each
+  exact in bfloat16, contract all planes in one single-pass bf16 matmul
+  with f32 accumulation, and recombine the planes in int32.
 """
 
 from __future__ import annotations
@@ -38,7 +42,11 @@ FORCE_INTERPRET_ENV = "REPRO_FORCE_INTERPRET"
 
 
 def on_tpu() -> bool:
-    """True when the default JAX backend is a TPU."""
+    """True when computations go to a TPU: the platform of
+    ``jax.default_device`` where one is set, else the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is not None:
+        return getattr(dev, "platform", dev) == "tpu"
     return jax.default_backend() == "tpu"
 
 
@@ -97,3 +105,22 @@ def onehot_dot(a: jax.Array, b: jax.Array, dimension_numbers) -> jax.Array:
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     return out.astype(jnp.int32)
+
+
+def byte_planes(x: jax.Array, n: int) -> list[jax.Array]:
+    """The int32 ``x`` as ``n`` byte planes, lowest first: every plane but
+    the top one holds 0..255, the top one ``x >> 8(n-1)`` keeps the sign.
+    With ``n`` = 4 (or ``x`` below ``2**(8n-1)`` in magnitude) every plane
+    lies in -128..255 and is exact in bfloat16."""
+    planes = [(x >> (8 * p)) & 0xFF for p in range(n - 1)]
+    return planes + [x >> (8 * (n - 1))]
+
+
+def from_byte_planes(planes) -> jax.Array:
+    """Inverse of :func:`byte_planes` in int32 arithmetic.  Summed planes
+    recombine to the sum of the values modulo 2**32, as an int32 sum
+    would wrap."""
+    out = planes[-1]
+    for plane in reversed(planes[:-1]):
+        out = (out << 8) + plane
+    return out

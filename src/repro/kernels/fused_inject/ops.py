@@ -1,13 +1,13 @@
 """jit'd public wrappers for the fused inject megakernel.
 
 Pads the event lanes to the VPU lane width (invalid lanes can never route:
-``valid=0``), squeezes the fan-out-1 routing table into the kernel's
-``[N, 4]`` int32 matrix (padded rows carry ``valid=0``), invokes the
+``valid=0``), stacks the four fields of all K fan-out entries of the
+routing table into one ``[N, 4K]`` int32 matrix (padded rows carry
+``valid=0``) and hands the kernel its byte planes, invokes the
 single-program Pallas kernel (interpret=True off-TPU), and re-orients the
-column-major kernel outputs into the :class:`FusedInjectOut` layout the
-fabric consumes.  The fused path requires ``table.fanout == 1`` (the
-paper's simplified single-destination scheme); the fabric falls back to
-the unfused chain otherwise.
+kernel outputs into the :class:`FusedInjectOut` layout the fabric
+consumes, deriving the traffic rows from the bucket counts.  Any fan-out
+K is fused: K is the table's static shape.
 """
 
 from __future__ import annotations
@@ -21,11 +21,11 @@ from repro.core import events as ev
 from repro.core import routing as rt
 from repro.kernels.common import resolve_interpret
 from repro.kernels.fused_inject.kernel import (fused_inject_pallas,
-                                               fused_lif_inject_pallas)
+                                               fused_lif_inject_pallas,
+                                               table_planes)
 from repro.kernels.fused_inject.ref import FusedInjectOut, FusedLifInjectOut
 
 LANES = 128
-SUBLANES = 8
 
 
 def _pad_to(x, m, axis, value):
@@ -38,30 +38,38 @@ def _pad_to(x, m, axis, value):
 
 
 def _table_matrix(table: rt.RoutingTable) -> tuple[jax.Array, int]:
-    if table.fanout != 1:
-        raise ValueError(
-            f"fused inject requires fanout 1, got {table.fanout}")
+    """``[Npad, 4K]`` int32: columns ``4j .. 4j+3`` hold fan-out entry j's
+    ``kernel.TABLE_COLS``; rows padded to the lane width carry
+    ``valid=0`` (the kernel puts N on lanes)."""
     tbl = jnp.stack([
-        table.dest_chip[:, 0].astype(jnp.int32),
-        table.dest_addr[:, 0].astype(jnp.int32),
-        table.delay[:, 0].astype(jnp.int32),
-        table.valid[:, 0].astype(jnp.int32),
-    ], axis=1)                                        # [N, 4]
-    return _pad_to(tbl, SUBLANES, 0, 0), table.n_neurons
+        field[:, j].astype(jnp.int32)
+        for j in range(table.fanout)
+        for field in (table.dest_chip, table.dest_addr, table.delay,
+                      table.valid)
+    ], axis=1)                                        # [N, 4K]
+    return _pad_to(tbl, LANES, 0, 0), table.n_neurons
 
 
-def _reach_row(reach, n_chips: int) -> jax.Array:
+def _reach_col(reach, n_chips: int) -> jax.Array | None:
     if reach is None:
-        return jnp.ones((1, n_chips), jnp.int32)
-    return jnp.asarray(reach).astype(jnp.int32).reshape(1, n_chips)
+        return None
+    return jnp.asarray(reach).astype(jnp.int32).reshape(n_chips, 1)
 
 
-def _reorient(slab2, counts_t, traffic_t, stats, *, nb, capacity):
-    b = slab2.shape[1]
-    slab = slab2.reshape(nb, capacity, b).transpose(0, 2, 1)
+def _reorient(slab, counts, stats, *, n_chips, buckets_per_chip, mode):
+    """Kernel outputs → :class:`FusedInjectOut`.  The traffic row per
+    destination chip sums that chip's bucket counts: both count the same
+    admitted lanes, and chip c owns buckets ``c*bpc .. c*bpc + bpc-1``
+    (in simplified mode only the first of them is used)."""
+    nb = n_chips * buckets_per_chip
+    counts, stats = counts[..., 0], stats[..., 0].T   # [B, NB], [4, B]
+    per_chip = counts.reshape(-1, n_chips, buckets_per_chip)
+    traffic = (per_chip[..., 0] if mode == "simplified"
+               else per_chip.sum(axis=-1))
     return FusedInjectOut(
-        slab=slab, counts=counts_t.T, sent=stats[0], overflow=stats[1],
-        wrap_expired=stats[2], lost=stats[3], traffic=traffic_t.T)
+        slab=slab[:, :nb].transpose(1, 0, 2), counts=counts,
+        sent=stats[0], overflow=stats[1], wrap_expired=stats[2],
+        lost=stats[3], traffic=traffic)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -86,13 +94,13 @@ def fused_inject(
     valid = _pad_to(events.valid.astype(jnp.int32), LANES, 1, 0)
     tbl, n_real = _table_matrix(table)
     out = fused_inject_pallas(
-        addr, time, valid, tbl, _reach_row(reach, n_chips),
+        addr, time, valid, table_planes(tbl), _reach_col(reach, n_chips),
         jnp.asarray(t0, jnp.int32).reshape(1, 1),
-        n_real=n_real, n_chips=n_chips,
+        n_real=n_real, fanout=table.fanout, n_chips=n_chips,
         buckets_per_chip=buckets_per_chip, capacity=capacity, mode=mode,
         time_window=time_window, interpret=interpret)
-    return _reorient(*out, nb=n_chips * buckets_per_chip,
-                     capacity=capacity)
+    return _reorient(*out, n_chips=n_chips,
+                     buckets_per_chip=buckets_per_chip, mode=mode)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -131,14 +139,15 @@ def fused_lif_inject(
         row(v, 0, jnp.float32), row(refrac, 0, jnp.int32),
         _pad_to(currents.astype(jnp.float32), LANES, 1, 0),
         params_f, row(params.refrac, 0, jnp.int32),
-        tbl, _reach_row(reach, n_chips),
+        table_planes(tbl), _reach_col(reach, n_chips),
         jnp.asarray(t0, jnp.int32).reshape(1, 1),
-        event_capacity=event_capacity, n_real=n_real, n_chips=n_chips,
-        buckets_per_chip=buckets_per_chip, capacity=capacity, mode=mode,
-        time_window=time_window, interpret=interpret)
+        event_capacity=event_capacity, n_real=n_real, fanout=table.fanout,
+        n_chips=n_chips, buckets_per_chip=buckets_per_chip,
+        capacity=capacity, mode=mode, time_window=time_window,
+        interpret=interpret)
     v_out, refrac_out, spikes, voltage = out[:4]
-    inject = _reorient(*out[4:], nb=n_chips * buckets_per_chip,
-                       capacity=capacity)
+    inject = _reorient(*out[4:], n_chips=n_chips,
+                       buckets_per_chip=buckets_per_chip, mode=mode)
     return FusedLifInjectOut(
         v=v_out[0, :n], refrac=refrac_out[0, :n], spikes=spikes[:, :n],
         voltage=voltage[:, :n], inject=inject)

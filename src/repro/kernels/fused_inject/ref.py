@@ -7,7 +7,7 @@ remaining deferral as extra slack, and flush-pack into column ``k`` of the
 ``int32[n_buckets, B, capacity]`` slab.  The Pallas megakernel
 (kernel.py) must reproduce it bitwise — tests/test_kernels.py drives both
 on hypothesis-generated edge cases, and the fabric keeps this chain as its
-fallback whenever the fused path does not apply (credit gate, fan-out > 1).
+path off the TPU and for credit-gated fabrics.
 
 The LIF-fronted variant (:func:`fused_lif_inject_ref`) prepends exactly
 the phase-1 substep chain of :func:`repro.snn.network._block_impl`:

@@ -16,8 +16,11 @@ Pins the tentpole contracts:
   * the conservation identity Σ sent == deposited + accounted + queued
     closes under ``use_pallas=True`` merge congestion;
   * launch-count pin: one superstep block traces exactly TWO pallas_call
-    equations — one fused inject, one fused drain — regardless of B
-    (counted in the jaxpr, nested scopes included).
+    equations — one fused inject, one fused drain — regardless of B and
+    of the fan-out (counted in the jaxpr, nested scopes included);
+  * fan-out 4 (the wafer module's): the fused inject ranks the K routed
+    lanes of an event in the e-major order of ``routing.route``, so when
+    a bucket overflows inside one event's entries the same entries drop.
 
 Everything runs in Pallas interpret mode on CPU (repro.kernels.common
 resolves the backend; REPRO_FORCE_INTERPRET=1 pins it in CI).
@@ -48,14 +51,15 @@ _TOPOS = [
 
 def _setup(B, *, n_chips=4, n=16, cap=4, bpc=2, mode="simplified",
            merge_rate=0, merge_depth=16, T=None, key=0, rate=0.6,
-           min_delay=2, max_delay=12, ring_depth=16):
+           min_delay=2, max_delay=12, ring_depth=16, fanout=1, table=None):
     """T per-step event buffers plus fused/unfused config twins.
 
     Unlike the superstep-vs-B=1 suites this one compares the SAME blocked
     schedule with and without the megakernels, so no slack constraint
     applies — the default delay range deliberately straddles the wrap
     window (min_delay < B for the larger B) to drive wrap_expired, and
-    the tiny buckets drive overflow.
+    the tiny buckets drive overflow.  ``table`` replaces the random
+    routing table (shared by every chip).
     """
     T = 2 * B if T is None else T
     k = jax.random.PRNGKey(key)
@@ -65,8 +69,9 @@ def _setup(B, *, n_chips=4, n=16, cap=4, bpc=2, mode="simplified",
         ring_depth=ring_depth, mode=mode, merge_rate=merge_rate,
         merge_depth=merge_depth, superstep=B)
     cfgp = dataclasses.replace(cfg, use_pallas=True)
-    table = rt.random_table(k, n, n_chips, max_delay=max_delay,
-                            min_delay=min_delay)
+    if table is None:
+        table = rt.random_table(k, n, n_chips, fanout=fanout,
+                                max_delay=max_delay, min_delay=min_delay)
     tables = jax.tree.map(
         lambda x: jnp.broadcast_to(x, (n_chips,) + x.shape), table)
     ks = jax.random.split(k, T)
@@ -113,17 +118,26 @@ def _assert_run_equal(r0, r1, msg=""):
 # Bitwise equality: fused vs unfused on the same blocked schedule
 # ---------------------------------------------------------------------------
 
+def _fanout_cases(cases):
+    """``(B, fanout)`` parameters; fan-out 1 keeps the bare ``B`` id."""
+    return pytest.mark.parametrize(
+        "B,fanout", cases,
+        ids=[f"{b}" if k == 1 else f"{b}-K{k}" for b, k in cases])
+
+
 @pytest.mark.parametrize("mode,merge_rate", [("simplified", 0),
                                              ("full", 0), ("full", 3)])
-@pytest.mark.parametrize("B", [1, 2, 4, 8])
-def test_fused_superstep_matches_unfused_bitwise(mode, merge_rate, B):
+@_fanout_cases([(1, 1), (2, 1), (4, 1), (8, 1), (1, 4), (4, 4)])
+def test_fused_superstep_matches_unfused_bitwise(mode, merge_rate, B,
+                                                 fanout):
     cfg, cfgp, ebs, tables, rings = _setup(B, mode=mode,
-                                           merge_rate=merge_rate)
+                                           merge_rate=merge_rate,
+                                           fanout=fanout)
     r0 = _run_blocks(fb.PulseFabric(cfg, transport="local"),
                      ebs, tables, rings)
     r1 = _run_blocks(fb.PulseFabric(cfgp, transport="local"),
                      ebs, tables, rings)
-    _assert_run_equal(r0, r1, msg=f"{mode}/r{merge_rate}/B{B} ")
+    _assert_run_equal(r0, r1, msg=f"{mode}/r{merge_rate}/B{B}/K{fanout} ")
     if merge_rate:
         # the hostile load must actually exercise the congestion path
         assert sum(int(np.asarray(s.merge_dropped).sum())
@@ -148,13 +162,16 @@ def test_fused_superstep_matches_on_routed_topologies(topo_name, topo, B):
 # Pipelined schedule: the in-kernel gate replaces the queue revert
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mode,merge_rate", [("simplified", 0),
-                                             ("full", 3)])
-def test_fused_pipeline_matches_unfused(mode, merge_rate):
+@pytest.mark.parametrize(
+    "mode,merge_rate,fanout",
+    [("simplified", 0, 1), ("full", 3, 1), ("simplified", 0, 4),
+     ("full", 3, 4)],
+    ids=["simplified-0", "full-3", "simplified-0-K4", "full-3-K4"])
+def test_fused_pipeline_matches_unfused(mode, merge_rate, fanout):
     B, F = 4, 3
     cfg, cfgp, ebs, tables, rings = _setup(
         B, T=B * F, mode=mode, merge_rate=merge_rate, min_delay=10,
-        max_delay=12, ring_depth=20)
+        max_delay=12, ring_depth=20, fanout=fanout)
     blocks = jax.tree.map(
         lambda *xs: jnp.stack(xs),
         *[jax.tree.map(lambda *ys: jnp.stack(ys),
@@ -178,15 +195,16 @@ def test_fused_pipeline_matches_unfused(mode, merge_rate):
         return fres.ring, delivered, stats
 
     _assert_run_equal(run(cfg), run(cfgp),
-                      msg=f"pipeline/{mode}/r{merge_rate} ")
+                      msg=f"pipeline/{mode}/r{merge_rate}/K{fanout} ")
 
 
 # ---------------------------------------------------------------------------
 # Credit gate: sequential feedback → fused inject falls back, stays bitwise
 # ---------------------------------------------------------------------------
 
-def test_fused_credit_gate_falls_back_and_matches():
-    cfg, cfgp, ebs, tables, rings = _setup(2, rate=0.9)
+@pytest.mark.parametrize("fanout", [1, 4])
+def test_fused_credit_gate_falls_back_and_matches(fanout):
+    cfg, cfgp, ebs, tables, rings = _setup(2, rate=0.9, fanout=fanout)
     flow = fb.FlowControlConfig(capacity=2, drain_rate=1)
     r0 = _run_blocks(fb.PulseFabric(cfg, transport="local", flow=flow),
                      ebs, tables, rings)
@@ -195,6 +213,44 @@ def test_fused_credit_gate_falls_back_and_matches():
     _assert_run_equal(r0, r1, msg="flow ")
     assert sum(int(np.asarray(s.stalled).sum()) for s in r0[2]) > 0, \
         "tight credits must stall"
+
+
+# ---------------------------------------------------------------------------
+# Fan-out order: an overflow that splits the K entries of one event
+# ---------------------------------------------------------------------------
+
+def _alternating_table(n, fanout, *, n_chips, delay):
+    """Entry j of every neuron goes to chip ``j % 2`` at a distinct
+    address: each event puts K/2 entries into each of two buckets, and
+    e-major and entry-major orders put different words in the slab."""
+    j = np.arange(fanout)[None, :]
+    i = np.arange(n)[:, None]
+    full = lambda x: jnp.asarray(np.broadcast_to(x, (n, fanout)),
+                                 jnp.int32)
+    return rt.RoutingTable(
+        dest_chip=full((j % 2) % n_chips),
+        dest_addr=full((i * fanout + j) % n),
+        delay=full(delay), valid=jnp.ones((n, fanout), bool))
+
+
+@pytest.mark.parametrize("B", [1, 4])
+def test_fused_overflow_splits_an_events_fanout_entries(B):
+    """Capacity 3 with two entries per event and bucket: the second
+    event's entries straddle the capacity, so the first one is packed and
+    the second one overflows — as in the unfused chain, bit for bit."""
+    n, fanout = 16, 4
+    table = _alternating_table(n, fanout, n_chips=4, delay=B + 2)
+    cfg, cfgp, ebs, tables, rings = _setup(
+        B, cap=3, bpc=1, table=table, min_delay=B + 2)
+    r0 = _run_blocks(fb.PulseFabric(cfg, transport="local"),
+                     ebs, tables, rings)
+    r1 = _run_blocks(fb.PulseFabric(cfgp, transport="local"),
+                     ebs, tables, rings)
+    _assert_run_equal(r0, r1, msg=f"split/B{B} ")
+    # Two entries per event and bucket against an odd capacity: every
+    # overflowing bucket cuts through one event's entries.
+    assert sum(int(np.asarray(s.overflow).sum()) for s in r0[2]) > 0, \
+        "some bucket must overflow inside one event's entries"
 
 
 # ---------------------------------------------------------------------------
@@ -253,10 +309,12 @@ def _count_pallas_calls(jaxpr) -> int:
 
 @pytest.mark.parametrize("mode,merge_rate", [("simplified", 0),
                                              ("full", 3)])
-@pytest.mark.parametrize("B", [1, 4])
-def test_superstep_traces_one_pallas_call_per_phase(mode, merge_rate, B):
+@_fanout_cases([(1, 1), (4, 1), (1, 4), (4, 4)])
+def test_superstep_traces_one_pallas_call_per_phase(mode, merge_rate, B,
+                                                    fanout):
     _, cfgp, ebs, tables, rings = _setup(B, mode=mode,
-                                         merge_rate=merge_rate)
+                                         merge_rate=merge_rate,
+                                         fanout=fanout)
     fab = fb.PulseFabric(cfgp, transport="local")
     block = jax.tree.map(lambda *xs: jnp.stack(xs), *ebs[:B])
     merge = fab.init_merge()
@@ -266,4 +324,5 @@ def test_superstep_traces_one_pallas_call_per_phase(mode, merge_rate, B):
     n = _count_pallas_calls(jaxpr.jaxpr)
     assert n == 2, (
         f"expected exactly 1 inject + 1 drain pallas_call per block, "
-        f"traced {n} (mode={mode}, merge_rate={merge_rate}, B={B})")
+        f"traced {n} (mode={mode}, merge_rate={merge_rate}, B={B}, "
+        f"fanout={fanout})")

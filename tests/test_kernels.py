@@ -250,11 +250,11 @@ def test_ssm_scan_matches_ref(b, t, din, n):
 # Fused inject megakernel: property sweep vs the composed reference
 # ---------------------------------------------------------------------------
 
-def _inject_case(seed, B, E, density, tight):
-    """Random event block + routing table, skewed at the edge cases:
-    density 0.0 is the all-invalid block, ``tight`` shrinks the bucket
-    capacity to force slab overflow, and t0 near 250 pushes deadlines
-    across the 255→0 wrap."""
+def _inject_case(seed, B, E, density, tight, fanout):
+    """Random event block + routing table of fan-out ``fanout``, skewed
+    at the edge cases: density 0.0 is the all-invalid block, ``tight``
+    shrinks the bucket capacity to force slab overflow, and t0 near 250
+    pushes deadlines across the 255→0 wrap."""
     from repro.core import events as ev
     from repro.core import routing as rt
 
@@ -266,7 +266,8 @@ def _inject_case(seed, B, E, density, tight):
     valid = jnp.asarray(rng.random((B, E)) < density)
     events = ev.EventBuffer(addr=addr, time=time, valid=valid)
     table = rt.random_table(jax.random.PRNGKey(seed % 997), n, 4,
-                            max_delay=12, min_delay=max(2, B))
+                            fanout=fanout, max_delay=12,
+                            min_delay=max(2, B))
     reach = (None if rng.random() < 0.5
              else jnp.asarray(rng.random(4) < 0.8))
     cap = 2 if tight else 8
@@ -276,13 +277,14 @@ def _inject_case(seed, B, E, density, tight):
 @settings(max_examples=12, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.sampled_from([1, 2, 4]),
        st.integers(1, 100), st.sampled_from(["simplified", "full"]),
-       st.sampled_from([0.0, 0.6, 1.0]), st.booleans())
-def test_fused_inject_property(seed, B, E, mode, density, tight):
+       st.sampled_from([0.0, 0.6, 1.0]), st.booleans(),
+       st.sampled_from([1, 4]))
+def test_fused_inject_property(seed, B, E, mode, density, tight, fanout):
     from repro.kernels.fused_inject import fused_inject
     from repro.kernels.fused_inject.ref import fused_inject_ref
 
     events, table, reach, t0, cap = _inject_case(seed, B, E, density,
-                                                 tight)
+                                                 tight, fanout)
     kw = dict(n_chips=4, buckets_per_chip=2, capacity=cap, mode=mode,
               time_window=4)
     got = fused_inject(events, table, reach, jnp.int32(t0), **kw)
@@ -291,13 +293,13 @@ def test_fused_inject_property(seed, B, E, mode, density, tight):
         np.testing.assert_array_equal(
             np.asarray(getattr(got, fld)), np.asarray(getattr(want, fld)),
             err_msg=f"{fld} (B={B} E={E} mode={mode} d={density} "
-                    f"tight={tight})")
+                    f"tight={tight} K={fanout})")
 
 
 @settings(max_examples=8, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.sampled_from([1, 4]),
-       st.sampled_from([3, 20, 64]))
-def test_fused_lif_inject_property(seed, B, event_capacity):
+       st.sampled_from([3, 20, 64]), st.sampled_from([1, 4]))
+def test_fused_lif_inject_property(seed, B, event_capacity, fanout):
     """The LIF-fronted megakernel (membrane update + spike detect fused
     ahead of the inject path) against lif_step + from_spikes + the
     composed chain — including event_capacity below and above the
@@ -316,7 +318,8 @@ def test_fused_lif_inject_property(seed, B, event_capacity):
     params = LIFParams(tau_m=10.0, v_th=1.0, v_reset=0.0, v_rest=0.0,
                        refrac=2)
     table = rt.random_table(jax.random.PRNGKey(seed % 991), n, 4,
-                            max_delay=12, min_delay=max(2, B))
+                            fanout=fanout, max_delay=12,
+                            min_delay=max(2, B))
     kw = dict(event_capacity=event_capacity, n_chips=4,
               buckets_per_chip=2, capacity=4, mode="simplified",
               time_window=1)

@@ -15,6 +15,9 @@ worker that runs this file loads the TPU compiler library.
 
 import dataclasses
 import functools
+import json
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,10 +25,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.bss2 import CONFIG
+from repro.core import pulse_comm as pc
 from repro.kernels import common
 from repro.kernels.bucket_pack.kernel import bucket_pack_pallas
 from repro.kernels.fused_drain.kernel import fused_drain_pallas
-from repro.kernels.fused_inject.kernel import fused_inject_pallas
+from repro.kernels.fused_inject.kernel import (TABLE_PLANES,
+                                               fused_inject_pallas,
+                                               table_rows)
 from repro.kernels.merge_sort.kernel import merge_sort_words_pallas
 from repro.snn import network as net
 
@@ -35,6 +41,9 @@ E = COMM.event_capacity
 LANES_IN = COMM.lanes_in                    # 46 * 32 = 1472 delivered lanes
 LANES_PAD = LANES_IN + (-LANES_IN) % 128    # fused_drain's 128-lane padding
 SORT_N = 2048                               # next power of two >= LANES_IN
+# The benchmark's wafer-module configuration, as its cell runs it.
+CELL_CONFIG = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+               / "configs" / "bss2-wafer.json")
 
 
 @pytest.fixture(scope="module")
@@ -88,17 +97,24 @@ def test_merge_sort_words_compiles(one_chip):
                             _spec(one_chip, (SORT_N,))))
 
 
-@pytest.mark.parametrize("B", [1, 4])
-def test_fused_inject_compiles(one_chip, B):
+@pytest.mark.parametrize("B,cull", [(1, False), (4, False), (4, True)],
+                         ids=["1", "4", "4-cull"])
+def test_fused_inject_compiles(one_chip, B, cull):
+    """The kernel at the wafer widths with the fan-out-4 table: the byte
+    planes of the ``[N, 4K]`` field matrix, K = 4; with a health column
+    when it culls."""
     kernel = functools.partial(
-        fused_inject_pallas, n_real=COMM.neurons_per_chip, n_chips=N_CHIPS,
+        fused_inject_pallas, n_real=COMM.neurons_per_chip,
+        fanout=COMM.fanout, n_chips=N_CHIPS,
         buckets_per_chip=COMM.buckets_per_chip,
         capacity=COMM.bucket_capacity, mode="simplified",
         time_window=COMM.time_window, interpret=False)
     s = functools.partial(_spec, one_chip)
+    table = s((TABLE_PLANES * table_rows(4 * COMM.fanout),
+               COMM.neurons_per_chip), jnp.bfloat16)
+    reach = s((N_CHIPS, 1)) if cull else None
     _assert_kernel(_compile(
-        kernel, s((B, E)), s((B, E)), s((B, E)),
-        s((COMM.neurons_per_chip, 4)), s((1, N_CHIPS)), s((1, 1))))
+        kernel, s((B, E)), s((B, E)), s((B, E)), table, reach, s((1, 1))))
 
 
 @pytest.mark.parametrize("mode,lanes,qdepth,rate", [
@@ -116,9 +132,10 @@ def test_fused_drain_compiles(one_chip, mode, lanes, qdepth, rate):
         s((1, 1))))
 
 
-def _run_block_program(sharding, comm):
+def _run_block_program(sharding, comm, **network):
     """net.run over one B-step block of the 46-chip network, compiled."""
-    cfg = net.NetworkConfig(comm=comm, neuron_model=CONFIG.neuron_model)
+    cfg = net.NetworkConfig(comm=comm, **{
+        "neuron_model": CONFIG.neuron_model, **network})
 
     def shapes():
         params = net.init_params(jax.random.PRNGKey(0), cfg)
@@ -133,16 +150,51 @@ def _run_block_program(sharding, comm):
 
 
 def test_network_block_compiles_jnp(one_chip):
+    """The off-TPU dispatch: JAX's backend here is the CPU, so at default
+    settings the fabric takes the jnp chain, even compiled for a TPU —
+    this pins the CPU path, which holds no kernel."""
     compiled = _run_block_program(one_chip, COMM)
     assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("fanout", [1, COMM.fanout])
 def test_network_block_compiles_pallas(one_chip, monkeypatch, fanout):
-    """The kernels as the fabric calls them: vmapped over 46 chips (fanout
-    1 takes the fused inject, fanout 4 ``bucket_pack``).  The wrappers
-    compile for the backend they find, so the test points them at the
-    TPU."""
+    """The kernels as the fabric calls them with ``use_pallas``: vmapped
+    over 46 chips (the fused inject at either fan-out, the fused drain).
+    The wrappers compile for the backend they find, so the test points
+    them at the TPU."""
     monkeypatch.setattr(common, "on_tpu", lambda: True)
     comm = dataclasses.replace(COMM, fanout=fanout, use_pallas=True)
     _assert_kernel(_run_block_program(one_chip, comm))
+
+
+_OPCODE = re.compile(r"= \S+ ([a-z-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _ops_under(hlo: str, scope: str, opcodes) -> list[str]:
+    """HLO instructions (fusion bodies included) with one of ``opcodes``
+    whose ``op_name`` lies under ``scope``."""
+    found = []
+    for line in hlo.splitlines():
+        op, name = _OPCODE.search(line), _OP_NAME.search(line)
+        if op and name and op.group(1) in opcodes and scope in name.group(1):
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_cell_config_injects_with_one_kernel_on_tpu(one_chip, monkeypatch):
+    """The benchmark cell's configuration at default settings, as the
+    fabric dispatches it on a TPU (the backend is pointed at the TPU): the
+    block's inject is one kernel launch, and no gather, scatter or sort is
+    left under ``fabric/inject``."""
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    config = json.loads(CELL_CONFIG.read_text())
+    network = dict(config["network"])
+    network.pop("crossbar_precision")
+    comm = pc.PulseCommConfig(**config["comm"])
+    hlo = _run_block_program(one_chip, comm, **network).as_text()
+    launches = re.findall(r'custom_call_target="tpu_custom_call"', hlo)
+    assert len(launches) == 1, f"{len(launches)} kernel launches per block"
+    left = _ops_under(hlo, "fabric/inject", ("gather", "scatter", "sort"))
+    assert not left, "\n".join(left)
