@@ -17,9 +17,13 @@ Phases (one chip, the default):
       closed on its totals;
   (b) the same run with ``use_pallas=True``: the fused drain compiled
       for the chip as well;
-  (c) full mode with ``merge_rate=8``, ``merge_depth=256``, both ways:
-      the fused drain's rate mode (bitonic sort plus the bounded queue);
-      and full mode with ``merge_rate=0``: its sort mode;
+  (c) the full scheme as the benchmark's ``bss2-wafer-merge`` cell runs
+      it (the ``comm`` group of ``MERGE_CONFIG``: 4 buckets per
+      destination renamed by 4-step windows, ``merge_rate=250``,
+      ``merge_depth=1024``), both ways: the fused drain's rate mode
+      (bitonic sort plus the bounded queue) at the cell's widths; and full
+      mode at one bucket per destination with ``merge_rate=0``: its sort
+      mode;
   (d) ``fanout=1``, both ways: the fused inject at the lab setup's
       fan-out.
 
@@ -64,6 +68,8 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
+# The benchmark's full-scheme configuration; phase (c) runs its comm group.
+MERGE_CONFIG = ROOT / "benchmarks" / "chip" / "configs" / "bss2-wafer-merge.json"
 STEPS = 64
 SUPERSTEP = 4
 DRIVE_RATE = 0.1      # Bernoulli rate of each external input per step
@@ -213,13 +219,15 @@ def one_chip(comm, seed: int, steps: int) -> list[str]:
     """Phases (a)-(d); returns the failures."""
     import numpy as np
 
+    from repro.core import pulse_comm as pc
     from repro.snn import network as net
 
+    merge_comm = pc.PulseCommConfig(
+        **json.loads(MERGE_CONFIG.read_text())["comm"])
     failures = []
     variants = [
         ("(a)/(b) simplified, fanout 4", comm),
-        ("(c) full, merge_rate 8", dataclasses.replace(
-            comm, mode="full", merge_rate=8, merge_depth=256)),
+        ("(c) full, merge cell", merge_comm),
         ("(c) full, sort", dataclasses.replace(comm, mode="full")),
         ("(d) fanout 1", dataclasses.replace(comm, fanout=1)),
     ]

@@ -539,9 +539,8 @@ class PulseFabric:
                 events, table, flow, sendq, t0)
         with phase_scope("fabric/exchange"):
             issued = pc.exchange_flush_issue(self.cfg, self.transport, slab)
-        with phase_scope("fabric/drain"):
-            ring, delivered, stats, merge = self._drain_block(
-                ring, merge, issued, inject, t0)
+        ring, delivered, stats, merge = self._drain_block(
+            ring, merge, issued, inject, t0)
         return ring, delivered, stats, flow, merge, sendq
 
     def _inject_block(
@@ -712,12 +711,19 @@ class PulseFabric:
         *,
         extra_ahead: int = 0,
         valid: jax.Array | None = None,
+        scope: str = "fabric/drain",
     ) -> tuple[dl.DelayRing, pc.Delivered, pc.CommStats,
                mg.MergeBuffer | None]:
         """Phase 3 for one chip: complete the issued exchange and replay
         the per-step schedule at the destination — merge substep k's
         arrivals against clock ``t0 + k`` and deposit with exactly the
         judgment the B=1 schedule would have applied.
+
+        The merge of full mode runs under its own ``fabric/merge`` scope,
+        between the exchange completion and the deposit (both under
+        ``scope``), so a trace tells the two apart; the fused drain
+        (``use_pallas``) does merge and deposit in one kernel, under
+        ``scope``.
 
         ``extra_ahead`` widens the deposit guard for the pipelined
         schedule: a block drained one block late has had the *following*
@@ -730,48 +736,50 @@ class PulseFabric:
         prologue block contributes nothing.
         """
         cfg = self.cfg
-        delivered_words, link = pc.exchange_flush_complete(
-            cfg, self.transport, issued)
-        b = delivered_words.shape[0]
-        if valid is not None:
-            delivered_words = jnp.where(
-                valid, delivered_words, jnp.int32(ev.WORD_SENTINEL))
-        lost_drain = jnp.zeros((b,), jnp.int32)
-        if self._deliverable is not None:
-            # Already-exchanged words can still be addressed to a chip
-            # that died while they were in flight (a pipeline carry
-            # restored across a recovery boundary): cull arrivals at a
-            # dead destination into lost_to_failure rather than silently
-            # depositing them into a dead chip's ring.  On the serial
-            # schedule nothing ever arrives at a dead chip (sources cull
-            # at inject), so this is the identity there.
-            me = self.transport.chip_index()
-            dele = jnp.asarray(self._deliverable)
-            alive_self = jnp.take(dele.reshape(-1),
-                                  me * cfg.n_chips + me)
-            lost_drain = jnp.where(
-                alive_self, 0,
-                jnp.sum(ev.word_valid(delivered_words).astype(jnp.int32),
-                        axis=1))
-            delivered_words = jnp.where(
-                alive_self, delivered_words, jnp.int32(ev.WORD_SENTINEL))
+        with phase_scope(scope):
+            delivered_words, link = pc.exchange_flush_complete(
+                cfg, self.transport, issued)
+            b = delivered_words.shape[0]
+            if valid is not None:
+                delivered_words = jnp.where(
+                    valid, delivered_words, jnp.int32(ev.WORD_SENTINEL))
+            lost_drain = jnp.zeros((b,), jnp.int32)
+            if self._deliverable is not None:
+                # Already-exchanged words can still be addressed to a chip
+                # that died while they were in flight (a pipeline carry
+                # restored across a recovery boundary): cull arrivals at a
+                # dead destination into lost_to_failure rather than silently
+                # depositing them into a dead chip's ring.  On the serial
+                # schedule nothing ever arrives at a dead chip (sources cull
+                # at inject), so this is the identity there.
+                me = self.transport.chip_index()
+                dele = jnp.asarray(self._deliverable)
+                alive_self = jnp.take(dele.reshape(-1),
+                                      me * cfg.n_chips + me)
+                lost_drain = jnp.where(
+                    alive_self, 0,
+                    jnp.sum(ev.word_valid(delivered_words).astype(jnp.int32),
+                            axis=1))
+                delivered_words = jnp.where(
+                    alive_self, delivered_words, jnp.int32(ev.WORD_SENTINEL))
 
         if cfg.use_pallas:
             # Megakernel fast path: merge + deposit for all B substeps in
             # a single pallas_call (repro.kernels.fused_drain) — the ring
             # and merge queue stay VMEM-resident across the block and the
             # gate (pipeline ``valid``) is applied in-kernel, replacing
-            # the queue-revert below.  Bitwise equal to the unfused chain
-            # (tests/test_fused.py).
+            # the queue-revert of _merge_block.  Bitwise equal to the
+            # unfused chain (tests/test_fused.py).
             from repro.kernels.fused_drain import ops as fd_ops
 
-            dmode = ("rate" if cfg.mode == "full" and self.merge_enabled
+            dmode = ("rate" if self.merge_enabled
                      else "sort" if cfg.mode == "full" else "passthrough")
-            fused = fd_ops.fused_drain(
-                ring, delivered_words,
-                merge.words if dmode == "rate" else None, t0,
-                mode=dmode, rate=cfg.merge_rate, extra_ahead=extra_ahead,
-                gate=valid)
+            with phase_scope(scope):
+                fused = fd_ops.fused_drain(
+                    ring, delivered_words,
+                    merge.words if dmode == "rate" else None, t0,
+                    mode=dmode, rate=cfg.merge_rate,
+                    extra_ahead=extra_ahead, gate=valid)
             ring = fused.ring
             if dmode == "rate":
                 merge = mg.MergeBuffer(words=fused.queue)
@@ -779,98 +787,89 @@ class PulseFabric:
             dep_expired = fused.dep_expired
             merge_dropped = fused.dropped
         else:
-            ring, out_words, dep_expired, merge_dropped, merge = (
-                self._drain_block_unfused(ring, merge, delivered_words,
-                                          t0, extra_ahead, valid))
+            merge_dropped = jnp.zeros((b,), jnp.int32)
+            if cfg.mode == "full":
+                with phase_scope("fabric/merge"):
+                    delivered_words, merge_dropped, merge = self._merge_block(
+                        merge, delivered_words, t0, valid)
+            with phase_scope(scope):
+                dep_expired = []
+                for k in range(b):
+                    ring, expired_k = dl.deposit_words(
+                        ring, delivered_words[k], now=t0 + k,
+                        min_ahead=extra_ahead + (b - 1) - k)
+                    dep_expired.append(expired_k)
+                dep_expired = jnp.stack(dep_expired)
+            out_words = delivered_words
 
-        stats_steps = []
-        for k in range(b):
-            last = k == b - 1
-            stats_steps.append(pc.CommStats(
-                sent=inject.sent[k],
-                overflow=inject.overflow[k],
-                merge_dropped=jnp.asarray(merge_dropped[k], jnp.int32),
-                expired=inject.wrap_expired[k] + dep_expired[k],
-                stalled=inject.stalled[k],
-                utilization=inject.utilization[k],
-                wire_bytes=inject.wire_bytes[k],
-                traffic=inject.traffic[k],
-                # The collective fires once per block: its link occupancy
-                # is attributed to the flush substep (zeros elsewhere).
-                # Per-block link_words totals match the per-step schedule
-                # exactly; link_backlog is judged at block granularity (B
-                # rounds of capacity — deferral smooths per-step bursts,
-                # so it is <= the per-step schedule's total).
-                link_words=link.words if last else jnp.zeros_like(
-                    link.words),
-                link_backlog=link.backlog if last else jnp.zeros_like(
-                    link.backlog),
-                lost_to_failure=inject.lost[k] + lost_drain[k],
-            ))
+        with phase_scope(scope):
+            stats_steps = []
+            for k in range(b):
+                last = k == b - 1
+                stats_steps.append(pc.CommStats(
+                    sent=inject.sent[k],
+                    overflow=inject.overflow[k],
+                    merge_dropped=jnp.asarray(merge_dropped[k], jnp.int32),
+                    expired=inject.wrap_expired[k] + dep_expired[k],
+                    stalled=inject.stalled[k],
+                    utilization=inject.utilization[k],
+                    wire_bytes=inject.wire_bytes[k],
+                    traffic=inject.traffic[k],
+                    # The collective fires once per block: its link
+                    # occupancy is attributed to the flush substep (zeros
+                    # elsewhere).  Per-block link_words totals match the
+                    # per-step schedule exactly; link_backlog is judged at
+                    # block granularity (B rounds of capacity — deferral
+                    # smooths per-step bursts, so it is <= the per-step
+                    # schedule's total).
+                    link_words=link.words if last else jnp.zeros_like(
+                        link.words),
+                    link_backlog=link.backlog if last else jnp.zeros_like(
+                        link.backlog),
+                    lost_to_failure=inject.lost[k] + lost_drain[k],
+                ))
+            stats = jax.tree.map(lambda *xs: jnp.stack(xs), *stats_steps)
+        return ring, pc.Delivered(words=out_words), stats, merge
 
-        delivered = pc.Delivered(words=out_words)
-        stats = jax.tree.map(lambda *xs: jnp.stack(xs), *stats_steps)
-        return ring, delivered, stats, merge
-
-    def _drain_block_unfused(
+    def _merge_block(
         self,
-        ring: dl.DelayRing,
         merge: mg.MergeBuffer | None,
         delivered_words: jax.Array,
         t0: jax.Array,
-        extra_ahead: int,
         valid: jax.Array | None,
-    ) -> tuple[dl.DelayRing, jax.Array, jax.Array, jax.Array,
-               mg.MergeBuffer | None]:
-        """The composed merge + per-substep deposit chain — the bitwise
-        reference the fused drain kernel is pinned against.  Returns
-        ``(ring, out_words[B, lanes], dep_expired[B], merge_dropped[B],
-        merge)``.
+    ) -> tuple[jax.Array, jax.Array, mg.MergeBuffer | None]:
+        """The temporal merge of full mode, unfused — with the deposit
+        loop of :meth:`_drain_block`, the bitwise reference the fused
+        drain kernel is pinned against.  Returns ``(words[B, lanes'],
+        merge_dropped[B], merge)``: each substep's stream in deadline
+        order, ``rate`` words a substep when the rate-limited queue runs.
         """
         cfg = self.cfg
         b = delivered_words.shape[0]
-        merge_out = None
-        merge_dropped = jnp.zeros((b,), jnp.int32)
-        if cfg.mode == "full" and self.merge_enabled:
-            # Stateful rate-limited merge: the B-step batch drains through
-            # the persistent queue with per-step emission against each
-            # substep's clock — congested events are *delayed to later
-            # steps*, not destroyed, and only queue overflow beyond
-            # merge_depth drops (counted per substep in merge_dropped), so
-            # delivered == emitted + queued + dropped holds every substep
-            # by construction.  The sort key comes straight from the low
-            # bits of the words — no decode on the hot path.
-            new_merge, merge_out, merge_dropped = mg.merge_drain_words(
-                merge, delivered_words, now0=t0, rate=cfg.merge_rate,
-                use_pallas=cfg.use_pallas,
-            )
-            if valid is not None:
-                # An empty carry must not advance the merge queue (its
-                # sentinel drain would still emit queued words).
-                merge = jax.tree.map(
-                    lambda n, o: jnp.where(valid, n, o), new_merge, merge)
-                merge_out = jnp.where(valid, merge_out,
-                                      jnp.int32(ev.WORD_SENTINEL))
-                merge_dropped = jnp.where(valid, merge_dropped, 0)
-            else:
-                merge = new_merge
-
-        out_words, dep_expired = [], []
-        for k in range(b):
-            now_k = t0 + k
-            defer_k = (b - 1) - k
-            if merge_out is not None:
-                words_k = merge_out[k]
-            elif cfg.mode == "full":
-                words_k = mg.merge_words(delivered_words[k], now_k)
-            else:
-                words_k = delivered_words[k]
-            ring, expired_k = dl.deposit_words(
-                ring, words_k, now=now_k, min_ahead=extra_ahead + defer_k)
-            out_words.append(words_k)
-            dep_expired.append(expired_k)
-        return (ring, jnp.stack(out_words), jnp.stack(dep_expired),
-                merge_dropped, merge)
+        if not self.merge_enabled:
+            words = jnp.stack([mg.merge_words(delivered_words[k], t0 + k)
+                               for k in range(b)])
+            return words, jnp.zeros((b,), jnp.int32), merge
+        # Stateful rate-limited merge: the B-step batch drains through the
+        # persistent queue with per-step emission against each substep's
+        # clock — congested events are *delayed to later steps*, not
+        # destroyed, and only queue overflow beyond merge_depth drops
+        # (counted per substep in merge_dropped), so delivered == emitted
+        # + queued + dropped holds every substep by construction.  The
+        # sort key comes straight from the low bits of the words — no
+        # decode on the hot path.
+        new_merge, words, merge_dropped = mg.merge_drain_words(
+            merge, delivered_words, now0=t0, rate=cfg.merge_rate,
+            use_pallas=cfg.use_pallas,
+        )
+        if valid is None:
+            return words, merge_dropped, new_merge
+        # An empty carry must not advance the merge queue (its sentinel
+        # drain would still emit queued words).
+        merge = jax.tree.map(lambda n, o: jnp.where(valid, n, o),
+                             new_merge, merge)
+        words = jnp.where(valid, words, jnp.int32(ev.WORD_SENTINEL))
+        return words, jnp.where(valid, merge_dropped, 0), merge
 
     def _chip_step(
         self,
@@ -1075,12 +1074,11 @@ class PulseFabric:
                 events, table, flow, sendq, t0)
         with phase_scope("fabric/exchange"):
             issued = pc.exchange_flush_issue(self.cfg, self.transport, slab)
-        with phase_scope("fabric/drain"):
-            ring, delivered, stats, merge = self._drain_block(
-                ring, merge,
-                pc.IssuedFlush(words=pending.words, link=pending.link),
-                pending.inject, pending.t0,
-                extra_ahead=b, valid=pending.valid)
+        ring, delivered, stats, merge = self._drain_block(
+            ring, merge,
+            pc.IssuedFlush(words=pending.words, link=pending.link),
+            pending.inject, pending.t0,
+            extra_ahead=b, valid=pending.valid)
         pending = pc.PipelineCarry(
             words=issued.words, link=issued.link, inject=inject,
             t0=jnp.asarray(t0, jnp.int32),
@@ -1098,12 +1096,11 @@ class PulseFabric:
         deposit guard (``extra_ahead=0`` — nothing popped its slots beyond
         the in-block deferral, exactly as if the serial schedule had
         drained it in place) and return a reset (empty) carry."""
-        with phase_scope("fabric/flush"):
-            ring, delivered, stats, merge = self._drain_block(
-                ring, merge,
-                pc.IssuedFlush(words=pending.words, link=pending.link),
-                pending.inject, pending.t0,
-                extra_ahead=0, valid=pending.valid)
+        ring, delivered, stats, merge = self._drain_block(
+            ring, merge,
+            pc.IssuedFlush(words=pending.words, link=pending.link),
+            pending.inject, pending.t0,
+            extra_ahead=0, valid=pending.valid, scope="fabric/flush")
         empty = pc.PipelineCarry(
             words=jnp.full_like(pending.words, ev.WORD_SENTINEL),
             link=jax.tree.map(jnp.zeros_like, pending.link),

@@ -3,11 +3,12 @@ paths bitwise (the explicit-transpose local path and the shard_map
 collective path), define full-mode semantics once, and account for credit
 flow control without losing events."""
 
+import os
 import subprocess
 import sys
 import textwrap
 import warnings
-
+from pathlib import Path
 from typing import NamedTuple
 
 import jax
@@ -417,18 +418,20 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     from repro.core import delays as dl, events as ev, fabric as fb
     from repro.core import pulse_comm as pc, routing as rt, transport as tp
 
+    import sys
+
     n, N = 4, 16
     mesh = Mesh(np.asarray(jax.devices()).reshape(n), ("chip",))
     key = jax.random.PRNGKey(0)
 
-    for mode, bpc, flow, merge_rate in [
-            ("simplified", 1, None, 0), ("full", 2, None, 0),
-            ("simplified", 2,
-             fb.FlowControlConfig(capacity=2, drain_rate=1), 0),
-            ("simplified", 2,
-             fb.FlowControlConfig(capacity=2, drain_rate=1,
-                                  retransmit_depth=16), 0),
-            ("full", 2, None, 3)]:
+    CASES = [
+        ("simplified", 1, None, 0), ("full", 2, None, 0),
+        ("simplified", 2, fb.FlowControlConfig(capacity=2, drain_rate=1), 0),
+        ("simplified", 2,
+         fb.FlowControlConfig(capacity=2, drain_rate=1, retransmit_depth=16),
+         0),
+        ("full", 2, None, 3)]
+    for mode, bpc, flow, merge_rate in [CASES[int(sys.argv[1])]]:
         cfg = pc.PulseCommConfig(
             n_chips=n, neurons_per_chip=N, n_inputs_per_chip=N,
             event_capacity=N, bucket_capacity=4, buckets_per_chip=bpc,
@@ -501,12 +504,18 @@ _EQUIV_SCRIPT = textwrap.dedent("""
 """)
 
 
-def test_local_and_shard_map_fabrics_bitwise_equal():
+@pytest.mark.parametrize("case", range(5), ids=[
+    "simplified", "full", "credit", "credit-retransmit", "full-merge"])
+def test_local_and_shard_map_fabrics_bitwise_equal(case):
+    """One configuration per subprocess (4 host devices each), so every
+    case has the whole timeout to itself."""
+    root = Path(__file__).resolve().parents[1]
+    env = {"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": "cpu"}
+    if "HOME" in os.environ:
+        env["HOME"] = os.environ["HOME"]
     out = subprocess.run(
-        [sys.executable, "-c", _EQUIV_SCRIPT],
-        capture_output=True, text=True, timeout=300,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo",
+        [sys.executable, "-c", _EQUIV_SCRIPT, str(case)],
+        capture_output=True, text=True, timeout=300, env=env, cwd=root,
     )
     assert "FABRIC_EQUIVALENCE_OK" in out.stdout, out.stderr[-3000:]

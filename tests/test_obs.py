@@ -12,7 +12,9 @@
     and the monitor CLI smoke.
 """
 
+import json
 import types
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +33,15 @@ from repro.snn import network as net
 # ---------------------------------------------------------------------------
 
 def _net(telemetry=None, pipeline=False, superstep=4, n_chips=4, nn=16,
-         ring=False):
+         ring=False, merge=False):
+    # merge: full mode, renamed buckets and a rate-limited merge queue
+    # that the offered load keeps occupied.
+    full = dict(mode="full", buckets_per_chip=2, merge_rate=2,
+                merge_depth=8) if merge else {}
     comm = pc.PulseCommConfig(
         n_chips=n_chips, neurons_per_chip=nn, n_inputs_per_chip=nn,
         event_capacity=nn, bucket_capacity=nn, ring_depth=16,
-        superstep=superstep)
+        superstep=superstep, **full)
     topo = tpo.ring(n_chips, link_latency=1) if (ring or pipeline) else None
     cfg = net.NetworkConfig(comm=comm, topology=topo, pipeline=pipeline,
                             telemetry=telemetry)
@@ -50,14 +56,16 @@ def _ext(cfg, T, key=7):
         (T, c.n_chips, c.n_inputs_per_chip)) < 0.35)
 
 
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["superstep", "pipelined"])
-def test_telemetry_bitwise_invariant(pipeline):
-    """Telemetry on vs off: identical spikes, voltages, and final rings;
-    the carry itself aggregates every substep."""
+@pytest.mark.parametrize("pipeline,merge", [(False, False), (True, False),
+                                            (False, True)],
+                         ids=["superstep", "pipelined", "merge"])
+def test_telemetry_bitwise_invariant(pipeline, merge):
+    """Telemetry on vs off: identical spikes, voltages, and final rings
+    (and merge queues); the carry itself aggregates every substep."""
     T = 16
-    cfg_off, params, s_off = _net(telemetry=None, pipeline=pipeline)
-    cfg_on, _, s_on = _net(telemetry=True, pipeline=pipeline)
+    cfg_off, params, s_off = _net(telemetry=None, pipeline=pipeline,
+                                  merge=merge)
+    cfg_on, _, s_on = _net(telemetry=True, pipeline=pipeline, merge=merge)
     ext = _ext(cfg_off, T)
 
     f_off, r_off = jax.jit(lambda s, e: net.run(cfg_off, params, s, e))(
@@ -80,6 +88,12 @@ def test_telemetry_bitwise_invariant(pipeline):
     sent_rec = int(np.asarray(r_on.stats.sent).sum())
     assert int(m.totals[obm.SCALAR_FIELDS.index("sent")]) == sent_rec
     assert sent_rec > 0
+    if merge:
+        np.testing.assert_array_equal(np.asarray(f_off.merge.words),
+                                      np.asarray(f_on.merge.words))
+        assert int(m.merge_occ_max) > 0
+        dropped = int(np.asarray(r_on.stats.merge_dropped).sum())
+        assert int(m.totals[obm.SCALAR_FIELDS.index("merge_dropped")]) == dropped
 
 
 def test_metrics_match_offline_reduction():
@@ -299,6 +313,62 @@ def test_network_scopes_reach_the_optimized_hlo(superstep):
         for line in hlo.splitlines() if 'op_name="' in line)
     for scope in NETWORK_SCOPES:
         assert scope in op_names, scope
+
+
+def _op_names(cfg, T=8):
+    """The ``op_name``s of ``net.run``'s optimized HLO."""
+    params = net.init_params(jax.random.PRNGKey(3), cfg)
+    state = net.init_state(cfg, params)
+    c = cfg.comm
+    ext = jnp.zeros((T, c.n_chips, c.n_inputs_per_chip))
+    hlo = jax.jit(lambda p, s, e: net.run(cfg, p, s, e)).lower(
+        params, state, ext).compile().as_text()
+    return [line.split('op_name="', 1)[1].split('"', 1)[0]
+            for line in hlo.splitlines() if 'op_name="' in line]
+
+
+@pytest.mark.parametrize("pipeline", [False, True],
+                         ids=["superstep", "pipelined"])
+def test_merge_scope_reaches_the_optimized_hlo(pipeline):
+    """Full mode with a rate-limited merge: the merge runs under its own
+    ``fabric/merge`` scope on both schedules, never inside
+    ``fabric/drain``."""
+    cfg, _, _ = _net(pipeline=pipeline, merge=True)
+    names = _op_names(cfg)
+    assert any("fabric/merge" in n for n in names)
+    assert any("fabric/drain" in n for n in names)
+    both = [n for n in names if "fabric/drain" in n and "fabric/merge" in n]
+    assert not both, both[:5]
+
+
+def test_simplified_program_has_no_merge_scope():
+    cfg, _, _ = _net()
+    names = _op_names(cfg)
+    assert any("fabric/drain" in n for n in names)
+    assert not [n for n in names if "fabric/merge" in n]
+
+
+def test_merge_cell_program_names_only_the_merge_beyond_the_phases():
+    """The benchmark's full-scheme configuration, shrunk, at the program's
+    defaults on the serial schedule: every ``fabric/`` op outside inject,
+    exchange and drain is a ``fabric/merge`` op.  The per-layer reading
+    ``merge_us_per_step`` (the trace's ``fabric_other`` layer) rests on
+    this."""
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "bss2-wafer-merge.json")
+    config = json.loads(path.read_text())
+    comm = dict(config["comm"], n_chips=4, neurons_per_chip=32,
+                n_inputs_per_chip=16, event_capacity=24, bucket_capacity=4)
+    network = dict(config["network"])
+    network.pop("crossbar_precision")
+    cfg = net.NetworkConfig(comm=pc.PulseCommConfig(**comm), **network)
+    names = _op_names(cfg)
+    phases = ("fabric/inject", "fabric/exchange", "fabric/drain")
+    other = [n for n in names
+             if "fabric/" in n and not any(p in n for p in phases)]
+    assert other
+    assert all("fabric/merge" in n for n in other), \
+        sorted({n for n in other if "fabric/merge" not in n})[:5]
 
 
 class _Annotations:

@@ -41,9 +41,9 @@ E = COMM.event_capacity
 LANES_IN = COMM.lanes_in                    # 46 * 32 = 1472 delivered lanes
 LANES_PAD = LANES_IN + (-LANES_IN) % 128    # fused_drain's 128-lane padding
 SORT_N = 2048                               # next power of two >= LANES_IN
-# The benchmark's wafer-module configuration, as its cell runs it.
-CELL_CONFIG = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
-               / "configs" / "bss2-wafer.json")
+# The benchmark's wafer-module configurations, as their cells run them.
+CELL_CONFIGS = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+                / "configs")
 
 
 @pytest.fixture(scope="module")
@@ -183,13 +183,16 @@ def _ops_under(hlo: str, scope: str, opcodes) -> list[str]:
     return found
 
 
-def test_cell_config_injects_with_one_kernel_on_tpu(one_chip, monkeypatch):
-    """The benchmark cell's configuration at default settings, as the
+@pytest.mark.parametrize("name", ["bss2-wafer", "bss2-wafer-merge"])
+def test_cell_config_injects_with_one_kernel_on_tpu(one_chip, monkeypatch,
+                                                     name):
+    """A benchmark cell's configuration at default settings, as the
     fabric dispatches it on a TPU (the backend is pointed at the TPU): the
     block's inject is one kernel launch, and no gather, scatter or sort is
-    left under ``fabric/inject``."""
+    left under ``fabric/inject`` — with the full scheme's 184 renamed
+    buckets per source too."""
     monkeypatch.setattr(common, "on_tpu", lambda: True)
-    config = json.loads(CELL_CONFIG.read_text())
+    config = json.loads((CELL_CONFIGS / f"{name}.json").read_text())
     network = dict(config["network"])
     network.pop("crossbar_precision")
     comm = pc.PulseCommConfig(**config["comm"])
