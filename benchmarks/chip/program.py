@@ -23,6 +23,8 @@ from benchmarks.chip.reference import network as ref
 
 INT_STATS = ("sent", "overflow", "merge_dropped", "expired", "stalled",
              "wire_bytes", "traffic", "lost_to_failure")
+# The event counters the window totals over every chunk it ran.
+COUNTERS = ("delivered", "sent", "overflow", "merge_dropped", "expired")
 # Keys of the program's configuration that choose an implementation, not
 # a deployment: a configuration file may not set them.
 IMPLEMENTATION_KEYS = ("use_pallas", "pipeline", "telemetry")
@@ -88,6 +90,13 @@ class Program:
     def spikes(host_rec) -> np.ndarray:
         return np.asarray(host_rec.spikes) > 0.5
 
+    @staticmethod
+    def counters(host_rec) -> dict:
+        """The :data:`COUNTERS` of one chunk's records, picked by field."""
+        st = host_rec.stats
+        return {"delivered": host_rec.delivered,
+                **{name: getattr(st, name) for name in COUNTERS[1:]}}
+
     def neutral_records(self, host_rec) -> dict:
         st = host_rec.stats
         out = {"spikes": self.spikes(host_rec),
@@ -139,6 +148,10 @@ class Reference:
     @staticmethod
     def spikes(host_rec) -> np.ndarray:
         return np.asarray(host_rec["spikes"])
+
+    @staticmethod
+    def counters(host_rec) -> dict:
+        return {name: host_rec[name] for name in COUNTERS}
 
     @staticmethod
     def neutral_records(host_rec) -> dict:

@@ -20,9 +20,15 @@ while the next runs.  With ``--trace 1`` the profiler covers the first
 ``trace_chunks`` chunks of the window and the result carries the
 per-layer metrics; otherwise the end-to-end ones.
 
-After the window the program's state is freed and the plain reference
-(``reference/``) replays every chunk from the same start with the same
-drive; ``check.py`` compares the two and decides ``correct``.
+The window keeps the full records of the chunks that start inside its
+first ``check.VERIFY_STEPS`` steps, and the program's state after the
+last of them; of every later chunk only the event counters the
+``window:`` line totals.  After the window the program's state is freed
+and the plain reference (``reference/``) replays those first chunks from
+the same start with the same drive; ``check.py`` compares their records
+and the state after them and decides ``correct``.  The replay, and the
+host memory that holds the records, are thus the same whatever the
+window's length or the program's speed.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -136,15 +142,33 @@ def import_program(root: Path) -> None:
         sys.path.insert(0, str(src))
 
 
-def closed_loop(system, drive, traffic, state, rates, seconds, tracer):
+class Kept:
+    """What the window keeps of its chunks: the drive rates and full
+    records of the first ``keep`` chunks, the program's state after the
+    last of them, and the event counters of every chunk."""
+
+    def __init__(self, system, keep: int, state):
+        self.system = system
+        self.keep = keep
+        self.records, self.counts = [], []
+        self.state = state
+
+    def add(self, rates, host) -> None:
+        """The next chunk's records, on the host."""
+        if len(self.counts) < self.keep:
+            self.records.append((rates, host))
+        self.counts.append(self.system.counters(host))
+
+
+def closed_loop(system, drive, traffic, state, rates, seconds, tracer, keep):
     """Trials: dispatch, wait for the records, make the next drive from
-    them.  Returns (window summary, chunks, final state)."""
+    them.  Returns (window summary, :class:`Kept`, final state)."""
     import jax
     import numpy as np
 
     from benchmarks.chip import traffic as bench_traffic
 
-    chunks, latencies = [], []
+    kept, latencies = Kept(system, keep, state), []
     tracer.start()
     w0 = end = time.perf_counter()
     j = 0
@@ -157,22 +181,24 @@ def closed_loop(system, drive, traffic, state, rates, seconds, tracer):
         end = time.perf_counter()
         latencies.append(end - t0)
         with jax.profiler.TraceAnnotation("trial/drive"):
-            chunks.append((rates, host))
+            if j < keep:
+                kept.state = state
+            kept.add(rates, host)
             rates = bench_traffic.next_rates(traffic, rates, system.spikes(host))
         j += 1
         tracer.chunks_done(j)
     return {"loop": "closed", "seconds": end - w0,
-            "latencies_s": latencies}, chunks, state
+            "latencies_s": latencies}, kept, state
 
 
-def open_loop(system, drive, traffic, state, rates, seconds, tracer):
+def open_loop(system, drive, traffic, state, rates, seconds, tracer, keep):
     """A stream: dispatch chunk j, then read chunk j - 1's records while j
-    runs.  Returns (window summary, chunks, final state)."""
+    runs.  Returns (window summary, :class:`Kept`, final state)."""
     import jax
     import numpy as np
 
     del traffic
-    chunks = []
+    kept = Kept(system, keep, state)
     tracer.start()
     w0 = end = time.perf_counter()
     pending = None
@@ -180,25 +206,29 @@ def open_loop(system, drive, traffic, state, rates, seconds, tracer):
     while j == 0 or end - w0 < seconds:
         with jax.profiler.TraceAnnotation("stream/dispatch"):
             state, rec = system.run(state, drive(np.int32(j), rates))
+        if j < keep:
+            kept.state = state
         if pending is not None:
             with jax.profiler.TraceAnnotation("stream/readback"):
-                chunks.append((rates, jax.device_get(pending)))
-            tracer.chunks_done(len(chunks))
+                kept.add(rates, jax.device_get(pending))
+            tracer.chunks_done(len(kept.counts))
         pending = rec
         j += 1
         end = time.perf_counter()
     with jax.profiler.TraceAnnotation("stream/readback"):
-        chunks.append((rates, jax.device_get(pending)))
+        kept.add(rates, jax.device_get(pending))
     end = time.perf_counter()
-    tracer.chunks_done(len(chunks))
-    return {"loop": "open", "seconds": end - w0, "latencies_s": []}, chunks, state
+    tracer.chunks_done(len(kept.counts))
+    return {"loop": "open", "seconds": end - w0, "latencies_s": []}, kept, state
 
 
 LOOPS = {"closed": closed_loop, "open": open_loop}
 
 
 def verify(cell, arrays, system, drive, chunks, final):
-    """Replay every chunk on the plain reference and compare."""
+    """Replay ``chunks``, the window's first, on the plain reference and
+    compare their records, then ``final``, the program's state after
+    them, with the reference's."""
     import jax
     import numpy as np
 
@@ -221,11 +251,12 @@ def verify(cell, arrays, system, drive, chunks, final):
 
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              trace: bool, *, require_tpu: bool = True, system_cls=None,
-             out=None, err=None) -> int:
+             out=None, err=None, verify_steps: int | None = None) -> int:
     """One run; prints the result line and returns 0, or raises
     :class:`RunError`.  ``system_cls`` puts another system in the
     program's place (the control), ``require_tpu=False`` lets the tests
-    drive a run on the CPU."""
+    drive a run on the CPU, ``verify_steps`` lets them compare fewer
+    steps than ``check.VERIFY_STEPS``."""
     out = out or sys.stdout
     err = err or sys.stderr
     from benchmarks.chip import spec
@@ -244,7 +275,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     import jax
     import numpy as np
 
-    from benchmarks.chip import data, program
+    from benchmarks.chip import check, data, program
     from benchmarks.chip import trace as bench_trace
 
     cache = enable_compile_cache(root)
@@ -266,23 +297,22 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
           f"{counter.count} trace/compile events", file=out, flush=True)
 
     tracer = Tracer(root, cell.traffic["trace_chunks"], trace)
+    keep = check.replay_chunks(cell.traffic["chunk_steps"],
+                               verify_steps or check.VERIFY_STEPS)
     before = counter.count
-    window, chunks, state = LOOPS[cell.traffic["loop"]](
-        system, drive, cell.traffic, state0, rates, seconds, tracer)
+    window, kept, state = LOOPS[cell.traffic["loop"]](
+        system, drive, cell.traffic, state0, rates, seconds, tracer, keep)
     compiles = counter.count - before
-    steps = len(chunks) * cell.traffic["chunk_steps"]
+    n_chunks = len(kept.counts)
+    steps = n_chunks * cell.traffic["chunk_steps"]
     window["steps"] = steps
     stats = devices[0].memory_stats() or {}
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices),
               "memory_peak_bytes": stats.get("peak_bytes_in_use")}
-    totals = dict.fromkeys(("delivered", "sent", "overflow", "merge_dropped",
-                            "expired"), 0)
-    for _, host in chunks:
-        rec = system.neutral_records(host)
-        for key in totals:
-            totals[key] += int(rec[key].sum())
-    print(f"window: {len(chunks)} chunks, {steps} steps in "
+    totals = {key: sum(int(np.sum(c[key])) for c in kept.counts)
+              for key in program.COUNTERS}
+    print(f"window: {n_chunks} chunks, {steps} steps in "
           f"{window['seconds']:.3f} s, {compiles} trace/compile events inside, "
           f"{totals['delivered'] / window['seconds']:.1f} delivered events/s; "
           f"event totals {totals}", file=out, flush=True)
@@ -297,8 +327,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             device["busy_s"] = summary["busy_s"]
             device["window_s"] = summary["window_s"]
 
-    final = system.final(state)
-    program.free(state, state0)
+    final = system.final(kept.state)
+    program.free(state, kept.state, state0)
+    chunks = kept.records
     tally = verify(cell, arrays, system, drive, chunks, final)
 
     ctx = {"setup_s": setup_s, "window": window, "trace": summary}

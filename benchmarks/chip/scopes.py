@@ -323,7 +323,7 @@ def trace_cell(root: Path, workload: str, seed: int, seconds: float, *,
 
     tracer = run.Tracer(root, cell.traffic["trace_chunks"], True)
     run.LOOPS[cell.traffic["loop"]](system, drive, cell.traffic, state0,
-                                    rates, seconds, tracer)
+                                    rates, seconds, tracer, 0)   # no replay
     if tracer.active:       # a window shorter than trace_chunks chunks
         jax.profiler.stop_trace()
     chunk_steps = cell.traffic["chunk_steps"]
